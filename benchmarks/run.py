@@ -55,6 +55,8 @@ def main() -> None:
                          "BENCH_<n>.json in the repo root)")
     args = ap.parse_args()
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (
         chaos_serve,
         kernel_bench,
@@ -87,7 +89,7 @@ def main() -> None:
     for name in chosen:
         try:
             all_benches[name]()
-        except Exception as e:  # keep the harness going; record the failure
+        except Exception as e:  # run the rest, record it, exit non-zero
             print(f"{name},ERROR,0,{type(e).__name__}: {e}", file=sys.stderr)
             print(f"{name},error,0,{type(e).__name__}")
             errors.append({"bench": name, "error": f"{type(e).__name__}: {e}"})
@@ -104,6 +106,9 @@ def main() -> None:
         "errors": errors,
     }, indent=1) + "\n")
     print(f"wrote {out}", file=sys.stderr)
+    if errors:
+        sys.exit(f"{len(errors)} benchmark(s) failed: "
+                 + ", ".join(e["bench"] for e in errors))
 
 
 if __name__ == "__main__":

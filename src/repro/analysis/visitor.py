@@ -19,11 +19,8 @@ import dataclasses
 from typing import Any, Callable, Iterator
 
 import jax
-import jax.core
 import numpy as np
-
-Jaxpr = jax.core.Jaxpr
-ClosedJaxpr = jax.core.ClosedJaxpr
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 
 def subjaxprs(param: Any) -> Iterator[Jaxpr]:

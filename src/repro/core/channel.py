@@ -29,9 +29,10 @@ from repro.core.types import (
 # SINR backend: 'einsum' is the XLA reference; 'pallas' routes the pairwise
 # interference reductions through the cell-block kernels in
 # repro.kernels.noma_rates (custom_vjp: forward AND backward stream blocked
-# tiles, so the GD gradient path runs tiled at paper scale), falling back to
-# interpret mode off-TPU; 'pallas_interpret' forces interpret mode. The
-# kernels are GATHER-FREE: they consume the raw (U, N, M) channel state plus
+# tiles, so the GD gradient path runs tiled at paper scale) compiled for the
+# TPU, with no fallback; 'pallas_interpret' runs the same kernels in the
+# Pallas interpreter (CPU tests). The kernels are GATHER-FREE: they consume
+# the raw (U, N, M) channel state plus
 # the int32 AP ids -- no g[:, ap, :] materialization, no same_cell mask
 # input, no padded operand copies -- and their VMEM budget is O(BN),
 # independent of the AP count. Passing a precomputed CellLayout
@@ -58,7 +59,7 @@ def set_sinr_backend(backend: str) -> str:
 
 
 def _pallas_interpret(backend: str) -> bool:
-    return backend == "pallas_interpret" or jax.default_backend() != "tpu"
+    return backend == "pallas_interpret"
 
 
 def make_env(

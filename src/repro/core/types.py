@@ -230,7 +230,8 @@ class GdConfig:
     # SINR backend traced into the solver's gradient path ("einsum" |
     # "pallas" | "pallas_interpret"). The Pallas pairwise kernel carries a
     # custom_vjp, so the GD hot loop itself can run stream-tiled at paper
-    # scale; "pallas" falls back to interpret mode off-TPU. Always passed
+    # scale; "pallas" always compiles for the TPU (it fails elsewhere) and
+    # only "pallas_interpret" runs the kernels in the interpreter. Always passed
     # explicitly to utility (never the channel-module global), so compiled
     # solver programs are keyed on -- and immune to -- backend switches.
     sinr_backend: str = static_field(default="einsum")

@@ -82,8 +82,7 @@ def _layout_blocks(layout, env, block_u, block_v):
 
 
 def _noma_pairwise(own, w_intra, w_power, g_raw, ap, uplink, descending,
-                   interpret, block_u, block_v, block_m, block_n, tiles,
-                   ap_mode):
+                   interpret, block_u, block_v, block_m, block_n, tiles):
     """Run the cell-block forward kernel on the UNPADDED operands.
 
     The kernel masks boundary blocks in-kernel (clamped cdiv grid), so no
@@ -95,13 +94,12 @@ def _noma_pairwise(own, w_intra, w_power, g_raw, ap, uplink, descending,
         own, own, w_intra, w_power, g_raw, ap, ap,
         descending=descending, uplink=uplink,
         block_u=block_u, block_v=block_v, block_m=block_m, block_n=block_n,
-        tiles=tiles, ap_mode=ap_mode, interpret=interpret,
+        tiles=tiles, interpret=interpret,
     )
 
 
 def _noma_pairwise_bwd(own, g_raw, ap, d_intra, d_inter, uplink, descending,
-                       interpret, block_u, block_v, block_m, block_n, tiles,
-                       ap_mode):
+                       interpret, block_u, block_v, block_m, block_n, tiles):
     """Backward twin of _noma_pairwise: the transposed-streaming kernels on
     the same unpadded raw-gain operands; returns (V, M) weight cotangents.
     tiles is the layout's BACKWARD list (the same tile set reordered for the
@@ -113,7 +111,7 @@ def _noma_pairwise_bwd(own, g_raw, ap, d_intra, d_inter, uplink, descending,
         d_intra.astype(jnp.float32), d_inter.astype(jnp.float32),
         descending=descending, uplink=uplink,
         block_u=block_u, block_v=block_v, block_m=block_m, block_n=block_n,
-        tiles=tiles, ap_mode=ap_mode, interpret=interpret,
+        tiles=tiles, interpret=interpret,
     )
     return d_wi, d_wp
 
@@ -123,7 +121,7 @@ def _zeros_cot(tree):
     zeros (weak types preserved via zeros_like), integer leaves get the
     float0 arrays custom_vjp requires for non-differentiable dtypes."""
     def z(x):
-        if jnp.issubdtype(jax.core.get_aval(x).dtype, jnp.inexact):
+        if jnp.issubdtype(jax.typeof(x).dtype, jnp.inexact):
             return jnp.zeros_like(x)
         return np.zeros(jnp.shape(x), jax.dtypes.float0)
     return jax.tree.map(z, tree)
@@ -174,23 +172,23 @@ def _bwd_tiles(layout):
     return None if layout is None else (layout.bwd_tile_v, layout.bwd_tile_u)
 
 
-_PAIR_NONDIFF = (3, 4, 5, 6, 7, 8)   # interpret + block sizes + ap_mode
+_PAIR_NONDIFF = (3, 4, 5, 6, 7)   # interpret + block sizes
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=_PAIR_NONDIFF)
 def _pairwise_up(env, tx, layout, interpret, block_u, block_v, block_m,
-                 block_n, ap_mode):
+                 block_n):
     return _pairwise_up_fwd(env, tx, layout, interpret, block_u, block_v,
-                            block_m, block_n, ap_mode)[0]
+                            block_m, block_n)[0]
 
 
 def _pairwise_up_fwd(env, tx, layout, interpret, block_u, block_v, block_m,
-                     block_n, ap_mode):
+                     block_n):
     own, g_raw, ap = _up_inputs(_used_env(env, layout))
     tx = _sort_in(tx.astype(jnp.float32), layout)
     out = _noma_pairwise(own, tx * own, tx, g_raw, ap, True, True,
                          interpret, block_u, block_v, block_m, block_n,
-                         _fwd_tiles(layout), ap_mode)
+                         _fwd_tiles(layout))
     # Residuals are exactly the kernel inputs -- no pairwise intermediates
     # are saved (own/g_raw/ap re-derive from env or layout.env, so the
     # residual adds only the O(U*M) own gains); the backward kernels
@@ -198,14 +196,13 @@ def _pairwise_up_fwd(env, tx, layout, interpret, block_u, block_v, block_m,
     return tuple(_sort_out(o, layout) for o in out), (env, layout, own)
 
 
-def _pairwise_up_bwd(interpret, block_u, block_v, block_m, block_n, ap_mode,
-                     res, ct):
+def _pairwise_up_bwd(interpret, block_u, block_v, block_m, block_n, res, ct):
     env, layout, own = res
     _, g_raw, ap = _up_inputs(_used_env(env, layout))
     d_i, d_x = (_sort_in(c, layout) for c in ct)
     d_wi, d_wp = _noma_pairwise_bwd(own, g_raw, ap, d_i, d_x, True, True,
                                     interpret, block_u, block_v, block_m,
-                                    block_n, _bwd_tiles(layout), ap_mode)
+                                    block_n, _bwd_tiles(layout))
     # Forward fed the kernel w_intra = tx * own and w_power = tx; chain back
     # to the one differentiable input. env and layout carry only GD-path
     # constants (zero cotangents, float0 for the int permutations/tiles).
@@ -218,29 +215,28 @@ _pairwise_up.defvjp(_pairwise_up_fwd, _pairwise_up_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=_PAIR_NONDIFF)
 def _pairwise_dn(env, tx, layout, interpret, block_u, block_v, block_m,
-                 block_n, ap_mode):
+                 block_n):
     return _pairwise_dn_fwd(env, tx, layout, interpret, block_u, block_v,
-                            block_m, block_n, ap_mode)[0]
+                            block_m, block_n)[0]
 
 
 def _pairwise_dn_fwd(env, tx, layout, interpret, block_u, block_v, block_m,
-                     block_n, ap_mode):
+                     block_n):
     own, g_raw, ap = _dn_inputs(_used_env(env, layout))
     tx = _sort_in(tx.astype(jnp.float32), layout)
     out = _noma_pairwise(own, tx, tx, g_raw, ap, False, False,
                          interpret, block_u, block_v, block_m, block_n,
-                         _fwd_tiles(layout), ap_mode)
+                         _fwd_tiles(layout))
     return tuple(_sort_out(o, layout) for o in out), (env, layout, own)
 
 
-def _pairwise_dn_bwd(interpret, block_u, block_v, block_m, block_n, ap_mode,
-                     res, ct):
+def _pairwise_dn_bwd(interpret, block_u, block_v, block_m, block_n, res, ct):
     env, layout, own = res
     _, g_raw, ap = _dn_inputs(_used_env(env, layout))
     d_i, d_x = (_sort_in(c, layout) for c in ct)
     d_wi, d_wp = _noma_pairwise_bwd(own, g_raw, ap, d_i, d_x, False, False,
                                     interpret, block_u, block_v, block_m,
-                                    block_n, _bwd_tiles(layout), ap_mode)
+                                    block_n, _bwd_tiles(layout))
     # Downlink feeds tx into both weight slots (the receiver-side own-gain
     # factor of eq. 8 is applied by the caller, outside the kernel).
     return _zeros_cot(env), _sort_out(d_wi + d_wp, layout), _zeros_cot(layout)
@@ -258,7 +254,6 @@ def noma_pairwise_up(
     block_m: int = DEFAULT_BLOCKS[2],
     block_n: int = DEFAULT_BLOCKS[3],
     layout: CellLayout | None = None,
-    ap_mode: str = "iota",
 ) -> tuple[jax.Array, jax.Array]:
     """Uplink (intra, inter) interference terms of eq. (5) via the Pallas
     kernels: the exact denominators consumed by channel.uplink_sinr.
@@ -277,7 +272,7 @@ def noma_pairwise_up(
     should use noma_pairwise_up_jit."""
     block_u, block_v = _layout_blocks(layout, env, block_u, block_v)
     return _pairwise_up(env, tx, layout, interpret, block_u, block_v,
-                        block_m, block_n, ap_mode)
+                        block_m, block_n)
 
 
 def noma_pairwise_dn(
@@ -289,7 +284,6 @@ def noma_pairwise_dn(
     block_m: int = DEFAULT_BLOCKS[2],
     block_n: int = DEFAULT_BLOCKS[3],
     layout: CellLayout | None = None,
-    ap_mode: str = "iota",
 ) -> tuple[jax.Array, jax.Array]:
     """Downlink (intra, inter) terms of eq. (8). The returned intra term is
     sum_v stronger*same * tx[v]; the caller multiplies by own-gain (the
@@ -299,7 +293,7 @@ def noma_pairwise_dn(
     noma_pairwise_up."""
     block_u, block_v = _layout_blocks(layout, env, block_u, block_v)
     return _pairwise_dn(env, tx, layout, interpret, block_u, block_v,
-                        block_m, block_n, ap_mode)
+                        block_m, block_n)
 
 
 def noma_uplink_rates(
@@ -312,7 +306,6 @@ def noma_uplink_rates(
     block_m: int = DEFAULT_BLOCKS[2],
     block_n: int = DEFAULT_BLOCKS[3],
     layout: CellLayout | None = None,
-    ap_mode: str = "iota",
 ) -> jax.Array:
     """Kernel-backed replacement for repro.core.channel.uplink_rates.
 
@@ -325,7 +318,7 @@ def noma_uplink_rates(
     intra, inter = noma_pairwise_up(env, tx, interpret=interpret,
                                     block_u=block_u, block_v=block_v,
                                     block_m=block_m, block_n=block_n,
-                                    layout=layout, ap_mode=ap_mode)
+                                    layout=layout)
     sinr = p_up[:, None] * own / (intra + inter + env.noise_up)
     bw = env.radio.bandwidth_up_hz / env.n_sub
     return beta_up * bw * jnp.log1p(sinr) / LOG2
@@ -341,7 +334,6 @@ def noma_downlink_rates(
     block_m: int = DEFAULT_BLOCKS[2],
     block_n: int = DEFAULT_BLOCKS[3],
     layout: CellLayout | None = None,
-    ap_mode: str = "iota",
 ) -> jax.Array:
     """Kernel-backed replacement for repro.core.channel.downlink_rates:
     assembles eq. (8)'s SINR from the pairwise terms (the intra term carries
@@ -353,7 +345,7 @@ def noma_downlink_rates(
     intra, inter = noma_pairwise_dn(env, tx, interpret=interpret,
                                     block_u=block_u, block_v=block_v,
                                     block_m=block_m, block_n=block_n,
-                                    layout=layout, ap_mode=ap_mode)
+                                    layout=layout)
     sinr = p_dn[:, None] * own / (intra * own + inter + env.noise_dn)
     bw = env.radio.bandwidth_dn_hz / env.n_sub
     return beta_dn * bw * jnp.log1p(sinr) / LOG2
@@ -365,8 +357,7 @@ def noma_downlink_rates(
 # trace overhead. layout stays an operand (its tile lists are array leaves;
 # the tile COUNT is pytree metadata, so a different cell population
 # recompiles -- by design, the grid size is the point).
-_NOMA_STATIC = ("interpret", "block_u", "block_v", "block_m", "block_n",
-                "ap_mode")
+_NOMA_STATIC = ("interpret", "block_u", "block_v", "block_m", "block_n")
 noma_pairwise_up_jit = functools.partial(jax.jit, static_argnames=_NOMA_STATIC)(
     noma_pairwise_up)
 noma_pairwise_dn_jit = functools.partial(jax.jit, static_argnames=_NOMA_STATIC)(

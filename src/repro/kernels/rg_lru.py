@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 
 def _kernel(log_a_ref, b_ref, h0_ref, out_ref, h_ref, *, block_s: int):
     si = pl.program_id(2)
@@ -65,8 +63,7 @@ def rg_lru_kernel(
         out_specs=pl.BlockSpec((bb, bs, bw), lambda bi, wi, si: (bi, si, wi)),
         out_shape=jax.ShapeDtypeStruct((bsz, s, w), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bb, bw), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary")
-        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(log_a, b, h0)

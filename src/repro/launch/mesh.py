@@ -6,11 +6,8 @@ import jax
 
 
 def _make(shape: tuple, axes: tuple):
-    # jax >= 0.5 grew sharding.AxisType; older releases only take (shape, axes).
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

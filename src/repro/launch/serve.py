@@ -9,6 +9,10 @@ uplink.
 Example (CPU, reduced config):
   PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b --reduced \
       --requests 4 --seq 64 --new-tokens 8
+
+main() returns the model, params, programs, plan, prompt, first edge
+logits and generated tokens, so a caller can check the run
+(chip_smoke.py).
 """
 from __future__ import annotations
 
@@ -21,13 +25,15 @@ import jax.numpy as jnp
 from repro import configs
 from repro.core import GdConfig, make_env, make_weights, profiles
 from repro.data import make_batch
+from repro.launch.cache import enable_compile_cache
 from repro.models import Model
 from repro.planning import PlannerEngine
 from repro.runtime.serve import make_split_serve, transfer_seconds
 from repro.core import channel
 
 
-def main():
+def main(argv=None) -> dict:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
     ap.add_argument("--reduced", action="store_true")
@@ -39,7 +45,7 @@ def main():
     ap.add_argument("--subchannels", type=int, default=4)
     ap.add_argument("--w-delay", type=float, default=0.7)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = configs.get(args.arch)
     if args.reduced:
@@ -75,11 +81,11 @@ def main():
     batch = make_batch(args.seed, 0, args.requests, args.seq, cfg.vocab_size)
     tokens = batch["tokens"]
     t0 = time.time()
-    act = progs.device_fn(tokens)
+    act = jax.block_until_ready(progs.device_fn(tokens))
     t_dev = time.time() - t0
     t_link = transfer_seconds(tokens.size, cfg.d_model, rate0)
     t0 = time.time()
-    logits = progs.edge_fn(act)
+    logits = jax.block_until_ready(progs.edge_fn(act))
     t_edge = time.time() - t0
     nxt = jnp.argmax(logits[:, -1], -1)
     print(f"[serve] {args.requests} reqs x {args.seq} tok: device {t_dev:.3f}s"
@@ -88,13 +94,15 @@ def main():
 
     # greedy continuation (device-side embedding, edge-side rest — each new
     # token repeats the split path)
-    seq = tokens
-    for i in range(args.new_tokens - 1):
+    seq, generated = tokens, [nxt]
+    for _ in range(args.new_tokens - 1):
         seq = jnp.concatenate([seq, nxt[:, None]], axis=1)
-        act = progs.device_fn(seq)
-        logits = progs.edge_fn(act)
-        nxt = jnp.argmax(logits[:, -1], -1)
+        nxt = jnp.argmax(progs.edge_fn(progs.device_fn(seq))[:, -1], -1)
+        generated.append(nxt)
     print(f"[serve] generated {args.new_tokens} tokens/request; done")
+    return {"model": model, "params": params, "progs": progs, "plan": plan,
+            "tokens": tokens, "logits": logits,
+            "generated": jnp.stack(generated, axis=1)}
 
 
 if __name__ == "__main__":

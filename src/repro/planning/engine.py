@@ -56,11 +56,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6 promoted shard_map out of experimental
-    from jax import shard_map as _shard_map_impl  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
 from repro.core import channel, li_gd
 from repro.core.types import (
     Array,
@@ -75,15 +70,11 @@ from repro.pshard import axis_size, fleet_axis
 
 
 def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """shard_map with replication checking off: the solver's lax.while_loop
-    has no replication rule on older jax, and every output here is fully
-    fleet-sharded anyway. Newer jax renamed/dropped the kwarg."""
-    try:
-        return _shard_map_impl(fn, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False)
-    except TypeError:
-        return _shard_map_impl(fn, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs)
+    """shard_map with varying-axes checking off: the Pallas kernels in the
+    solver declare their outputs without a ``vma``, and every output here
+    is fully fleet-sharded anyway."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # -- compile observability --------------------------------------------------

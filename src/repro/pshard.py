@@ -108,7 +108,11 @@ def spec_for(mesh: Mesh, logical_axes: tuple, shape: tuple,
 
 
 def ambient_mesh():
-    """The physical mesh activated via `with mesh:` (trace-time), or None."""
+    """The physical mesh activated via `with mesh:` (trace-time), or None.
+
+    Private import on purpose: JAX 0.9.0 has no public getter for the mesh
+    a `with mesh:` block enters (jax.sharding.get_mesh/get_abstract_mesh
+    see only jax.set_mesh), and the callers here use `with mesh:`."""
     from jax._src import mesh as mesh_lib
     m = mesh_lib.thread_resources.env.physical_mesh
     return None if m.empty else m

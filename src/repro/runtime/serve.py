@@ -55,9 +55,9 @@ def jit_decode_step(model: Model, mesh, batch: int, max_len: int):
 # --------------------------------------------------------------------------
 class SplitPrograms(NamedTuple):
     device_fn: object     # (tokens, frontend=None) -> activation (B, S, D);
-                          # closes over the device-side stage params
-    edge_fn: object       # (activation, frontend=None) -> logits; closes over
-                          # the edge-side stage params + unembed
+                          # bound to the device-side stage params
+    edge_fn: object       # (activation, frontend=None) -> logits; bound to
+                          # the edge-side stage params + final norm/unembed
     split_layer: int
     act_bytes_per_token: int
 
@@ -84,35 +84,48 @@ def _split_params(model: Model, params, s: int):
 
 
 def make_split_serve(model: Model, params, s: int):
-    """Build device/edge programs for split point s (decoder-only archs)."""
+    """Build device/edge programs for split point s (decoder-only archs).
+
+    The weights are operands of the jitted programs, bound here, never
+    constants closed over: baked in, a full-width model's weights land in
+    every executable (one per sequence length) and in the host memory of
+    every compile."""
     cfg = model.cfg
     a_stages, b_stages = _split_params(model, params, s)
+    a_specs = [spec for spec, _ in a_stages]
+    b_specs = [spec for spec, _ in b_stages]
 
-    def device_fn(tokens, frontend=None):
-        b, sl = tokens.shape
+    def aux(x, frontend):
+        b, sl = x.shape[:2]
         pos = jnp.broadcast_to(jnp.arange(sl, dtype=jnp.int32)[None], (b, sl))
-        x = embed_lookup(params["embed"], tokens)
-        aux = {"pos": pos,
-               "frontend": None if frontend is None else frontend.astype(COMPUTE_DTYPE),
-               "moe_impl": model.moe_impl, "moe_capacity": model.moe_capacity}
-        for spec, p_st in a_stages:
-            x, _, _ = model._run_stage(spec, p_st, x, aux, None)
+        return {"pos": pos,
+                "frontend": None if frontend is None else frontend.astype(COMPUTE_DTYPE),
+                "moe_impl": model.moe_impl, "moe_capacity": model.moe_capacity}
+
+    def device_fn(p, tokens, frontend=None):
+        embed, stage_params = p
+        x, a = embed_lookup(embed, tokens), aux(tokens, frontend)
+        for spec, p_st in zip(a_specs, stage_params):
+            x, _, _ = model._run_stage(spec, p_st, x, a, None)
         return x.astype(COMPUTE_DTYPE)
 
-    def edge_fn(x, frontend=None):
-        b, sl, _ = x.shape
-        pos = jnp.broadcast_to(jnp.arange(sl, dtype=jnp.int32)[None], (b, sl))
-        aux = {"pos": pos,
-               "frontend": None if frontend is None else frontend.astype(COMPUTE_DTYPE),
-               "moe_impl": model.moe_impl, "moe_capacity": model.moe_capacity}
-        for spec, p_st in b_stages:
-            x, _, _ = model._run_stage(spec, p_st, x, aux, None)
-        x = model._final_norm(params, x)
-        return logits_out(x, params["unembed"], cfg.vocab_size)
+    def edge_fn(p, x, frontend=None):
+        head, stage_params = p
+        a = aux(x, frontend)
+        for spec, p_st in zip(b_specs, stage_params):
+            x, _, _ = model._run_stage(spec, p_st, x, a, None)
+        x = model._final_norm(head, x)
+        return logits_out(x, head["unembed"], cfg.vocab_size)
 
+    head = {k: v for k, v in params.items() if k not in ("stages", "embed")}
     act_bytes = cfg.d_model * 2  # bf16 residual stream per token
-    return SplitPrograms(device_fn=jax.jit(device_fn), edge_fn=jax.jit(edge_fn),
-                         split_layer=s, act_bytes_per_token=act_bytes)
+    return SplitPrograms(
+        device_fn=functools.partial(
+            jax.jit(device_fn),
+            (params["embed"], [p_st for _, p_st in a_stages])),
+        edge_fn=functools.partial(
+            jax.jit(edge_fn), (head, [p_st for _, p_st in b_stages])),
+        split_layer=s, act_bytes_per_token=act_bytes)
 
 
 def transfer_seconds(n_tokens: int, d_model: int, rate_bps: float) -> float:

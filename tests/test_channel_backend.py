@@ -1,5 +1,6 @@
-"""SINR backend switch: the Pallas pairwise-kernel path must reproduce the
-einsum reference (acceptance: within 1e-5) for both link directions."""
+"""SINR backend switch: the Pallas pairwise-kernel path (run here in the
+Pallas interpreter) must reproduce the einsum reference (acceptance: within
+1e-5) for both link directions."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -23,7 +24,7 @@ def test_pallas_backend_matches_einsum(u, n, m):
 
     for fn, p in ((channel.uplink_sinr, p_up), (channel.downlink_sinr, p_dn)):
         ref = np.asarray(fn(env, beta, p, backend="einsum"))
-        ker = np.asarray(fn(env, beta, p, backend="pallas"))
+        ker = np.asarray(fn(env, beta, p, backend="pallas_interpret"))
         np.testing.assert_allclose(ker, ref, rtol=1e-5, atol=1e-5 * ref.max())
 
 
@@ -31,10 +32,10 @@ def test_pallas_backend_rates_match(small_env):
     env = small_env
     beta, p_up, p_dn = _vars(jax.random.PRNGKey(2), env.n_users, env.n_sub)
     r_ref = np.asarray(channel.uplink_rates(env, beta, p_up, backend="einsum"))
-    r_ker = np.asarray(channel.uplink_rates(env, beta, p_up, backend="pallas"))
+    r_ker = np.asarray(channel.uplink_rates(env, beta, p_up, backend="pallas_interpret"))
     np.testing.assert_allclose(r_ker, r_ref, rtol=1e-5, atol=1e-5 * r_ref.max())
     d_ref = np.asarray(channel.downlink_rates(env, beta, p_dn, backend="einsum"))
-    d_ker = np.asarray(channel.downlink_rates(env, beta, p_dn, backend="pallas"))
+    d_ker = np.asarray(channel.downlink_rates(env, beta, p_dn, backend="pallas_interpret"))
     np.testing.assert_allclose(d_ker, d_ref, rtol=1e-5, atol=1e-5 * d_ref.max())
 
 

@@ -148,6 +148,34 @@ def _gather_free_case(u, n, m, seed=0):
     return env, tx, own_up, own_dn
 
 
+def _run_pairwise(env, own, w_intra, tx, uplink, descending, with_layout,
+                  block_u, block_v, block_m, block_n):
+    """noma_pairwise_kernel in the caller's user order: directly on the
+    unsorted operands, or through a CellLayout (AP-sorted operands and the
+    block-diagonal tile list, outputs mapped back by the inverse
+    permutation)."""
+    from repro.kernels import build_cell_layout
+    from repro.kernels.noma_rates import noma_pairwise_kernel
+
+    if not with_layout:
+        return noma_pairwise_kernel(
+            own, own, w_intra, tx, (env.g_up if uplink else env.g_dn).astype(
+                jnp.float32), env.ap, env.ap, descending=descending,
+            uplink=uplink, block_u=block_u, block_v=block_v, block_m=block_m,
+            block_n=block_n, interpret=True)
+    layout = build_cell_layout(env, block_u=block_u, block_v=block_v)
+    senv = layout.env
+    g_raw = (senv.g_up if uplink else senv.g_dn).astype(jnp.float32)
+    perm = lambda x: jnp.take(x, layout.perm, axis=0)
+    own_s = perm(own)
+    out = noma_pairwise_kernel(
+        own_s, own_s, perm(w_intra), perm(tx), g_raw, senv.ap, senv.ap,
+        descending=descending, uplink=uplink, block_u=layout.block_u,
+        block_v=layout.block_v, block_m=block_m, block_n=block_n,
+        tiles=(layout.tile_u, layout.tile_v), interpret=True)
+    return tuple(jnp.take(o, layout.inv, axis=0) for o in out)
+
+
 @pytest.mark.parametrize("u,n,m,bu,bv,bm,bn", [
     (10, 3, 6, 4, 8, 8, 2),    # non-divisible U/V/M, mismatched block_u/block_v
     (20, 3, 6, 16, 8, 8, 8),   # block_n > n_aps (clamped in-kernel)
@@ -156,28 +184,24 @@ def _gather_free_case(u, n, m, seed=0):
 ])
 @pytest.mark.parametrize("uplink", [True, False])
 @pytest.mark.parametrize("descending", [True, False])
-@pytest.mark.parametrize("ap_mode", ["iota", "onehot"])
+@pytest.mark.parametrize("with_layout", [False, True])
 def test_noma_gather_free_parity(u, n, m, bu, bv, bm, bn, uplink, descending,
-                                 ap_mode):
+                                 with_layout):
     """The gather-free cell-block kernels (raw gains + int32 AP ids in, AP
     selection and same_cell derived in-kernel, N-tiled accumulators) match
     BOTH oracles at 1e-5: the old gathered-kernel reference (explicit
     g_vu = g[*, ap, *] + same mask -- the math the pre-gather kernel
     computed) and the gather-free reference, for both links, both SIC
-    orders, and both AP-structure modes -- including N not divisible by
-    block_n, where boundary N blocks are iota-masked."""
-    from repro.kernels.noma_rates import noma_pairwise_kernel
-
+    orders, and both schedules (the dense tile grid and a CellLayout's
+    block-diagonal list) -- including N not divisible by block_n, where
+    boundary N blocks are iota-masked."""
     env, tx, own_up, own_dn = _gather_free_case(u, n, m, seed=u + n)
     own = own_up if uplink else own_dn
     g_raw = (env.g_up if uplink else env.g_dn).astype(jnp.float32)
     w_intra = tx * own if uplink else tx
 
-    ki, kx = noma_pairwise_kernel(own, own, w_intra, tx, g_raw, env.ap,
-                                  env.ap, descending=descending,
-                                  uplink=uplink, block_u=bu, block_v=bv,
-                                  block_m=bm, block_n=bn, ap_mode=ap_mode,
-                                  interpret=True)
+    ki, kx = _run_pairwise(env, own, w_intra, tx, uplink, descending,
+                           with_layout, bu, bv, bm, bn)
     gi, gx = ref.noma_pairwise_gather_free_ref(own, own, w_intra, tx, g_raw,
                                                env.ap, descending=descending,
                                                uplink=uplink)
@@ -192,21 +216,17 @@ def test_noma_gather_free_parity(u, n, m, bu, bv, bm, bn, uplink, descending,
 
 
 @pytest.mark.parametrize("uplink", [True, False])
-@pytest.mark.parametrize("ap_mode", ["iota", "onehot"])
-def test_noma_gather_free_single_cell_inter_is_exactly_zero(uplink, ap_mode):
+@pytest.mark.parametrize("with_layout", [False, True])
+def test_noma_gather_free_single_cell_inter_is_exactly_zero(uplink,
+                                                            with_layout):
     """N=1: every user shares the one AP, so the inter-cell term must be
     EXACTLY zero (the in-kernel other-cell mask is identically false),
-    not merely small."""
-    from repro.kernels.noma_rates import noma_pairwise_kernel
-
+    not merely small -- with and without a CellLayout."""
     env, tx, own_up, own_dn = _gather_free_case(9, 1, 12, seed=3)
     own = own_up if uplink else own_dn
-    g_raw = (env.g_up if uplink else env.g_dn).astype(jnp.float32)
     w_intra = tx * own if uplink else tx
-    _, inter = noma_pairwise_kernel(own, own, w_intra, tx, g_raw, env.ap,
-                                    env.ap, descending=uplink, uplink=uplink,
-                                    block_u=8, block_v=8, block_m=8,
-                                    ap_mode=ap_mode, interpret=True)
+    _, inter = _run_pairwise(env, own, w_intra, tx, uplink, uplink,
+                             with_layout, 8, 8, 8, 8)
     np.testing.assert_array_equal(np.asarray(inter), 0.0)
 
 

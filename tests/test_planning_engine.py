@@ -117,14 +117,15 @@ def test_engine_cache_reuse(gd_cfg):
 
 
 def test_engine_pallas_backend_matches_einsum_plan(small_env, weights):
-    """Acceptance: PlannerEngine(sinr_backend='pallas').plan(env) returns the
-    same split/allocation as the einsum engine on a small env ('pallas'
-    resolves to interpret mode on CPU)."""
+    """Acceptance: the kernel backend's plan(env) returns the same
+    split/allocation as the einsum engine on a small env (the kernels run
+    in the Pallas interpreter on CPU)."""
     cfg = GdConfig(max_iters=40, optimizer="adam")
     e_ein = PlannerEngine(profiles.nin(), weights=weights, cfg=cfg)
     e_pal = PlannerEngine(profiles.nin(), weights=weights, cfg=cfg,
-                          sinr_backend="pallas")
-    assert e_ein.sinr_backend == "einsum" and e_pal.sinr_backend == "pallas"
+                          sinr_backend="pallas_interpret")
+    assert (e_ein.sinr_backend == "einsum"
+            and e_pal.sinr_backend == "pallas_interpret")
     s1 = e_ein.plan(small_env)
     s2 = e_pal.plan(small_env)
     assert int(s1.plan.s) == int(s2.plan.s)
@@ -142,11 +143,11 @@ def test_engine_pallas_backend_matches_einsum_plan(small_env, weights):
 
 def test_engine_pallas_backend_fleet_paths(weights):
     """The custom_vjp'd pallas_call must stay batchable: plan_many and
-    replan_many (the vmapped fleet paths) with sinr_backend='pallas' agree
+    replan_many (the vmapped fleet paths) with the kernel backend agree
     with the einsum fleet programs per member."""
     cfg = GdConfig(max_iters=25, optimizer="adam")
     e_pal = PlannerEngine(profiles.nin(), weights=weights, cfg=cfg,
-                          sinr_backend="pallas")
+                          sinr_backend="pallas_interpret")
     e_ein = PlannerEngine(profiles.nin(), weights=weights, cfg=cfg)
     envs = stack_envs([make_env(jax.random.PRNGKey(s), 8, 2, 4)
                        for s in (0, 1)])
