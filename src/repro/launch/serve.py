@@ -100,7 +100,7 @@ def main(argv=None) -> dict:
         nxt = jnp.argmax(progs.edge_fn(progs.device_fn(seq))[:, -1], -1)
         generated.append(nxt)
     print(f"[serve] generated {args.new_tokens} tokens/request; done")
-    return {"model": model, "params": params, "progs": progs, "plan": plan,
+    return {"model": model, "params": params, "plan": plan,
             "tokens": tokens, "logits": logits,
             "generated": jnp.stack(generated, axis=1)}
 
