@@ -1,0 +1,13 @@
+"""The benchmark's own CPU tests: run them with
+
+    JAX_PLATFORMS=cpu python -m pytest perfbench/tests -q
+"""
+import os
+import sys
+from pathlib import Path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+CHECKOUT = Path(__file__).resolve().parents[2]
+for p in (str(CHECKOUT / "src"), str(CHECKOUT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
