@@ -16,9 +16,7 @@ blackouts, telemetry dropout/corruption, and service-time spikes. The
 headline metric is goodput/sec -- finite, in-deadline completions -- not
 raw completions: a NaN service time "completes" in one epoch, so the
 unguarded arm's completion counter is inflated by requests that never
-really ran (the rows record both so the artifact shows the gap).
-Availability is the fraction of epochs a finite plan was on the air;
-recovery stats come from the ladder's own counters.
+really ran.
 
   PYTHONPATH=src python -m benchmarks.chaos_serve            # full sweep
   PYTHONPATH=src python -m benchmarks.chaos_serve --quick    # CI smoke
@@ -29,8 +27,7 @@ import argparse
 
 import jax
 
-from benchmarks.paper_common import audit_meta, emit
-from repro.analysis import audit_faults, guard_trace_audit
+from benchmarks.paper_common import emit
 from repro.core import profiles
 from repro.core.types import GdConfig
 from repro.online import (
@@ -91,14 +88,6 @@ def run(quick: bool = False) -> None:
     mixes = ("full",) if quick else ("fades", "telemetry", "full")
     n_epochs = 40 if quick else 120
 
-    # The audit verdict travels with the perf rows: the hardened epoch
-    # program under injection + the plan-word guard against NoHostTransfer;
-    # the full run adds the executing chaos-loop probe (zero steady-state
-    # recompiles, rate swap mints no cache keys, served plan stays finite).
-    report = (guard_trace_audit(label="chaos_serve") if quick
-              else audit_faults(label="chaos_serve"))
-    audit = audit_meta(report)
-
     rows = []
     per_point: dict[tuple, dict] = {}
     for outage in outages:
@@ -114,32 +103,11 @@ def run(quick: bool = False) -> None:
                 h = m["history"]
                 availability = (sum(h["plan_finite"])
                                 / max(len(h["plan_finite"]), 1))
-                extra = {
-                    "outage": outage, "mix": mix, "arm": arm,
-                    "epochs": m["epochs"],
-                    "completed": m["completed"], "goodput": m["goodput"],
-                    "requests_per_s": m["requests_per_s"],
-                    "dropped": m["dropped"], "shed": m["shed"],
-                    "deadline_missed": m["deadline_missed"],
-                    "availability": availability,
-                    "bad_plans": m.get("bad_plans", 0),
-                    "faulted_epochs": sum(1 for f in h["faulted"] if f),
-                }
-                if "ladder_stage" in m:      # laddered arms only
-                    extra.update({
-                        "quarantines": m["quarantines"],
-                        "holds": m["holds"],
-                        "baseline_fallbacks": m["baseline_fallbacks"],
-                        "cold_replans": m["ladder_cold_replans"],
-                        "recoveries": m["recoveries"],
-                        "mean_recovery_epochs": m["mean_recovery_epochs"],
-                    })
                 rows.append((
                     f"out{outage:g}:{mix}:{arm}:goodput_per_s",
                     m["goodput_per_s"],
                     "finite in-deadline completions/sec under fault "
                     "injection (raw completions inflate on NaN service)",
-                    extra,
                 ))
 
     # The claim the artifact exists to record: at the 20%-outage operating
@@ -155,24 +123,9 @@ def run(quick: bool = False) -> None:
             f"out{outage:g}:{gate_mix}:ladder_over_no_ladder", ratio,
             "goodput/sec ratio, hardened over unguarded; no-ladder served "
             f"non-finite plans: {not all(nl['history']['plan_finite'])}",
-            {"outage": outage, "mix": gate_mix,
-             "closed_goodput_per_s": cl["goodput_per_s"],
-             "no_ladder_goodput_per_s": nl["goodput_per_s"],
-             "no_ladder_availability":
-                 sum(nl["history"]["plan_finite"])
-                 / max(len(nl["history"]["plan_finite"]), 1)},
         ))
 
-    emit("chaos_serve", rows,
-         meta={"arrival_rate_hz": STREAM.arrival_rate_hz,
-               "epoch_dt_s": STREAM.epoch_dt_s,
-               "deadline_s": STREAM.deadline_s,
-               "edge_capacity": SERVICE.edge_capacity,
-               "load_gain": SERVICE.load_gain,
-               "replan_every": SERVICE.replan_every,
-               "quarantine_epochs": LADDER.quarantine_epochs,
-               "baseline_after": LADDER.baseline_after},
-         audit=audit)
+    emit("chaos_serve", rows)
 
     # Sanity gates (fail loudly rather than record a dead chaos loop):
     # the hardened arm must never put a non-finite plan on the air, and at

@@ -12,11 +12,7 @@ The edge degrades with load (ServiceConfig.load_gain inflates the suffix
 compute by 1 + gain * (occupancy + backlog) / capacity), which the static
 profile cannot see: its s* stays put while the queue saturates. The
 closed loop's measured profile re-prices edge compute, s* rises (keep
-more layers on device) and completions/sec recover. Rows carry the full
-decision record: scheduled + QoS-forced replan counts, the s* trajectory
-(run-length encoded), tail latencies and deadline misses -- plus the
-repro.analysis audit verdict for the measured-profile replan program the
-closed arm dispatches.
+more layers on device) and completions/sec recover.
 
   PYTHONPATH=src python -m benchmarks.online_serve            # 3 points
   PYTHONPATH=src python -m benchmarks.online_serve --quick    # CI smoke
@@ -27,9 +23,8 @@ import argparse
 
 import jax
 
-from benchmarks.paper_common import audit_meta, emit
-from repro.analysis import audit_online_replan
-from repro.core import make_env, profiles
+from benchmarks.paper_common import emit
+from repro.core import profiles
 from repro.core.types import GdConfig
 from repro.online import OnlineLoop, ServiceConfig, StreamConfig
 from repro.planning import PlannerEngine
@@ -39,17 +34,6 @@ CFG = GdConfig(step_size=3e-2, eps=1e-4, max_iters=60, optimizer="adam")
 STREAM = StreamConfig(arrival_rate_hz=30.0, epoch_dt_s=0.02, deadline_s=0.2)
 SERVICE = ServiceConfig(edge_capacity=4, queue_depth=32, load_gain=8.0,
                         replan_every=5)
-
-
-def _rle(xs: list[int]) -> list[list[int]]:
-    """Run-length encode a trajectory: [[value, run], ...]."""
-    out: list[list[int]] = []
-    for x in xs:
-        if out and out[-1][0] == x:
-            out[-1][1] += 1
-        else:
-            out.append([int(x), 1])
-    return out
 
 
 def _episode(n_users: int, feedback: bool, n_epochs: int, seed: int) -> dict:
@@ -64,14 +48,6 @@ def run(quick: bool = False) -> None:
     users = (6,) if quick else (4, 8, 12)
     n_epochs = 30 if quick else 70
 
-    # The audit verdict travels with the perf rows: the closed arm's replan
-    # program, traced at measured-profile avals, against the base rules.
-    audit_eng = PlannerEngine(profiles.nin(), cfg=CFG)
-    audit_env = make_env(jax.random.PRNGKey(0), n_users=users[0], n_aps=2,
-                         n_sub=3)
-    audit = audit_meta(audit_online_replan(audit_eng, audit_env,
-                                           label="online_serve"))
-
     rows = []
     per_point: dict[int, dict[str, dict]] = {}
     for u in users:
@@ -85,19 +61,6 @@ def run(quick: bool = False) -> None:
                 f"u{u}:{arm}:requests_per_s", m["requests_per_s"],
                 "completions/sec under load-degraded edge; closed arm "
                 "replans on the measured profile",
-                {
-                    "n_users": u, "arm": arm, "epochs": m["epochs"],
-                    "offered_per_s": m["offered_per_s"],
-                    "dropped": m["dropped"],
-                    "deadline_missed": m["deadline_missed"],
-                    "p50_s": h["p50"][-1], "p95_s": h["p95"][-1],
-                    "miss_rate": h["miss_rate"][-1],
-                    "replans": m["replans"],
-                    "forced_replans": m["forced_replans"],
-                    "qos_triggers": m["qos_triggers"],
-                    "peak_congestion": max(h["congestion"]),
-                    "s_trajectory": _rle(h["s"]),
-                },
             ))
 
     # The claim the artifact exists to record: under induced edge load the
@@ -111,19 +74,9 @@ def run(quick: bool = False) -> None:
             f"u{u}:closed_over_static", gain,
             "requests/sec ratio; s* diverged from static plan: "
             f"{s_moved}",
-            {"n_users": u, "s_diverged": bool(s_moved),
-             "static_s": _rle(st["history"]["s"]),
-             "closed_s": _rle(cl["history"]["s"])},
         ))
 
-    emit("online_serve", rows,
-         meta={"arrival_rate_hz": STREAM.arrival_rate_hz,
-               "epoch_dt_s": STREAM.epoch_dt_s,
-               "deadline_s": STREAM.deadline_s,
-               "edge_capacity": SERVICE.edge_capacity,
-               "load_gain": SERVICE.load_gain,
-               "replan_every": SERVICE.replan_every},
-         audit=audit)
+    emit("online_serve", rows)
 
     # Sanity gates (benchmark fails loudly rather than record a dead loop):
     # every closed-arm point must have replanned, and at least one point

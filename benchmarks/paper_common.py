@@ -3,19 +3,10 @@ scaled to CPU: the paper's 1250 users / 250 subchannels Monte-Carlo is run
 at reduced but proportional scale; densities and ratios follow Sec. VI.A)."""
 from __future__ import annotations
 
-import time
-
 import jax
 import jax.numpy as jnp
 
-from repro.core import (
-    GdConfig,
-    baselines,
-    make_env,
-    make_weights,
-    planner,
-    profiles,
-)
+from repro.core import GdConfig, make_env, make_weights, planner
 
 CFG = GdConfig(step_size=5e-3, max_iters=250)
 W_T = 0.5          # equal tradeoff weights unless a figure sweeps them
@@ -37,43 +28,8 @@ def mean_outcomes(n_users, n_aps, n_sub, prof, w_T=W_T, seeds=N_SEEDS,
     return acc
 
 
-# Every emit() call also appends machine-readable rows here so the harness
-# (benchmarks/run.py) can write the BENCH_<n>.json perf-trajectory artifact.
-ROWS: list[dict] = []
-
-
-def emit(name: str, rows: list[tuple], meta: dict | None = None,
-         audit: dict | None = None):
-    """CSV rows: (label, value, derived-annotation) or (label, value,
-    derived, row_meta) -- a 4th dict entry attaches per-row key/values
-    (e.g. timing spread, tuning-table entries) on top of the shared meta.
-    meta: extra key/values attached to every JSON row (e.g. kernel layout +
-    block sizes) so BENCH_<n>.json artifacts stay comparable across kernel
-    redesigns. Per-row meta wins on key collisions.
-    audit: a repro.analysis verdict for the program these rows measure
-    (e.g. audit_meta(report)), stamped as the rows' 'audit' field -- perf
-    numbers in the artifact then carry the proof that the program they
-    timed still satisfies the kernel invariants. A per-row 'audit' in
-    row_meta overrides it (the autotune table audits per candidate)."""
-    for r in rows:
-        label, val, derived = r[0], r[1], r[2]
-        row_meta = r[3] if len(r) > 3 else None
+def emit(name: str, rows: list[tuple]):
+    """Print ``name,label,value,derived`` CSV lines, one per (label,
+    value, derived-annotation) row."""
+    for label, val, derived in rows:
         print(f"{name},{label},{val:.6g},{derived}")
-        row = {"bench": name, "label": label, "value": float(val),
-               "derived": derived}
-        if meta:
-            row.update(meta)
-        if audit is not None:
-            row["audit"] = audit
-        if row_meta:
-            row.update(row_meta)
-        ROWS.append(row)
-
-
-def audit_meta(report) -> dict:
-    """Compress an analysis.AuditReport into the artifact's audit field:
-    verdict, the rules that ran, and the findings (if any) as strings."""
-    d = {"ok": report.ok, "rules": list(report.rules)}
-    if report.findings:
-        d["findings"] = [str(f) for f in report.findings]
-    return d

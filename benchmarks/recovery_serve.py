@@ -1,4 +1,4 @@
-"""Recovery benchmark (BENCH_6): goodput and recovery cost under crashes.
+"""Recovery benchmark: goodput and recovery cost under crashes.
 
 Each arm drives the SAME chaos-hardened serving loop (same traffic, same
 faults, same seed) through repro.state.CrashSupervisor with crashes
@@ -30,8 +30,7 @@ import time
 
 import jax
 
-from benchmarks.paper_common import audit_meta, emit
-from repro.analysis import audit_recovery, retrace_probe
+from benchmarks.paper_common import emit
 from repro.core import profiles
 from repro.core.types import GdConfig
 from repro.online import (
@@ -95,13 +94,6 @@ def run(quick: bool = False) -> None:
     cadence = 8 if quick else 10
     crashes = (25,) if quick else (50, 95)
 
-    # The audit verdict travels with the rows: quick checks the restore
-    # path is retrace-free; the full run also proves bit-exact resume and
-    # clean journal replay (the executing resume probe).
-    report = (retrace_probe(label="recovery_serve") if quick
-              else audit_recovery(label="recovery_serve"))
-    audit = audit_meta(report)
-
     rows = []
     results: dict[str, dict] = {}
     with tempfile.TemporaryDirectory() as td:
@@ -109,28 +101,16 @@ def run(quick: bool = False) -> None:
             m = _episode(n_epochs, crashes, cadence, checkpointed, td)
             results[arm] = m
             wall = max(m["wall_s"], 1e-9)
-            extra = {
-                "arm": arm, "epochs": m["epochs"],
-                "crashes": len(crashes), "restarts": m["restarts"],
-                "cold_restarts": m["cold_restarts"],
-                "recovery_epochs": m["supervisor_recovery_epochs"],
-                "restored_from": m["restored_from"],
-                "snapshots_saved": m["snapshots_saved"],
-                "goodput": m["goodput"], "wall_s": m["wall_s"],
-                "goodput_per_s_sim": m["goodput_per_s"],
-            }
             rows.append((
                 f"{arm}:goodput_per_wall_s", m["goodput"] / wall,
                 "finite in-deadline completions per wall-clock second, "
                 "crash recovery included (at smoke scale restart "
                 "recompilation dominates the wall; recovery_epochs is the "
-                "scale-free recovery cost)",
-                extra))
+                "scale-free recovery cost)"))
             rows.append((
                 f"{arm}:recovery_epochs", m["supervisor_recovery_epochs"],
                 "epochs re-executed after crashes (durable: bounded by the "
-                "snapshot cadence; no-checkpoint: the whole prefix)",
-                extra))
+                "snapshot cadence; no-checkpoint: the whole prefix)"))
 
         # Snapshot tax: crash-free wall time, snapshotting vs bare.
         base = _episode(n_epochs, (), cadence, checkpointed=False, tmpdir=td)
@@ -140,10 +120,7 @@ def run(quick: bool = False) -> None:
         rows.append((
             "snapshot_overhead_pct", overhead,
             f"wall-time cost of async snapshots every {cadence} epochs, "
-            "zero crashes",
-            {"bare_wall_s": base["wall_s"], "snap_wall_s": snap["wall_s"],
-             "snapshots_saved": snap["snapshots_saved"],
-             "cadence": cadence}))
+            "zero crashes"))
 
     dur, noc = results["durable"], results["no_checkpoint"]
     saved = (noc["supervisor_recovery_epochs"]
@@ -151,18 +128,9 @@ def run(quick: bool = False) -> None:
     rows.append((
         "recovery_epochs_saved", saved,
         "re-executed epochs avoided by durable snapshots across the crash "
-        "schedule",
-        {"durable": dur["supervisor_recovery_epochs"],
-         "no_checkpoint": noc["supervisor_recovery_epochs"],
-         "crashes": list(crashes)}))
+        "schedule"))
 
-    emit("recovery_serve", rows,
-         meta={"n_epochs": n_epochs, "cadence": cadence,
-               "crashes": list(crashes), "seed": SEED,
-               "arrival_rate_hz": STREAM.arrival_rate_hz,
-               "epoch_dt_s": STREAM.epoch_dt_s,
-               "replan_every": SERVICE.replan_every},
-         audit=audit)
+    emit("recovery_serve", rows)
 
     # Sanity gates: recovery must actually recover (all crashes survived,
     # full epoch count served, every served plan finite), and snapshots

@@ -32,7 +32,8 @@ from repro.analysis.rules import (
 )
 from repro.core.types import NetworkEnv
 from repro.kernels.noma_rates import dense_tile_count
-from repro.planning.engine import PlannerEngine, compile_log, stack_envs
+from repro.obs import compile_log
+from repro.planning.engine import PlannerEngine, stack_envs
 
 
 def engine_rules(engine: PlannerEngine, env: NetworkEnv) -> list[Rule]:

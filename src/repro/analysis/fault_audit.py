@@ -81,7 +81,7 @@ def chaos_loop_probe(label: str = "faults") -> AuditReport:
     traces nothing, a fault-mix swap mints no cache keys, and the served
     plan ends the episode finite."""
     from repro.faults import FaultConfig, LadderConfig
-    from repro.planning.engine import compile_log
+    from repro.obs import compile_log
 
     report = AuditReport(programs=[f"{label}:chaos_loop"],
                          rules=["stable_signature", "cache_key_discipline"])

@@ -32,7 +32,8 @@ from repro.analysis.audit import audit
 from repro.analysis.report import AuditReport, Finding, merge_reports
 from repro.analysis.rules import StableSignature, base_rules
 from repro.core.types import GdConfig, NetworkEnv
-from repro.planning.engine import PlannerEngine, compile_log
+from repro.obs import compile_log
+from repro.planning.engine import PlannerEngine
 
 
 def _measured_like(engine: PlannerEngine, scale: float):

@@ -160,7 +160,7 @@ def resume_probe(label: str = "recovery") -> AuditReport:
 def retrace_probe(label: str = "recovery") -> AuditReport:
     """Restore into a fresh loop must mint zero steady-state compiles and
     no extra engine cache entries beyond the uninterrupted run's."""
-    from repro.planning.engine import compile_log
+    from repro.obs import compile_log
     from repro.state import load_snapshot, save_snapshot
 
     report = AuditReport(

@@ -27,6 +27,9 @@ Design notes
   instead of re-biasing from zero -- without this, sign-like early Adam steps
   near the previous optimum defeat early stopping and warm starts can *lose*
   to cold starts at moderate epoch-to-epoch correlation.
+* Named scopes mark the phases on a device trace (see repro.obs): gd_iter
+  (the while_loop of iterations), warm_gate (the online start's two utility
+  probes) and greedy_rounding (the sequential rounding scans).
 """
 from __future__ import annotations
 
@@ -231,7 +234,8 @@ def gd_solve(
     mom0 = zero_mom if init_mom is None else init_mom
     norm0 = _project(init_norm, beta_min)
     state0 = (norm0, mom0, gamma_fn(norm0), jnp.int32(0), jnp.bool_(False))
-    norm, mom, gamma, it, _ = jax.lax.while_loop(cond, body, state0)
+    with jax.named_scope("gd_iter"):
+        norm, mom, gamma, it, _ = jax.lax.while_loop(cond, body, state0)
     _, g = grad_fn(norm)
     return GdResult(norm=norm, gamma=gamma, iters=it, grad_norm=_tree_norm(g),
                     mom=mom, opt_steps=steps0 + it)
@@ -314,8 +318,9 @@ def gd_loop(
                 return _utility(env, prof, s, to_physical(n, env), w,
                                 backend=cfg.sinr_backend)
 
-            pick_warm = jnp.logical_and(use_warm,
-                                        gamma_at(w0) <= gamma_at(carry_norm))
+            with jax.named_scope("warm_gate"):
+                pick_warm = jnp.logical_and(
+                    use_warm, gamma_at(w0) <= gamma_at(carry_norm))
             sel = lambda a, b: jnp.where(pick_warm, a, b)
             start = jax.tree.map(sel, w0, carry_norm)
             mom0 = jax.tree.map(lambda x: jnp.where(pick_warm, x, 0.0),
@@ -395,7 +400,8 @@ def greedy_round_up(env: NetworkEnv, beta: Array, p: Array) -> Array:
         return assigned_interf + add, m.astype(jnp.int32)
 
     init = jnp.zeros_like(own)
-    _, subs = jax.lax.scan(step, init, jnp.arange(env.n_users))
+    with jax.named_scope("greedy_rounding"):
+        _, subs = jax.lax.scan(step, init, jnp.arange(env.n_users))
     return subs
 
 
@@ -415,8 +421,9 @@ def greedy_round_dn(env: NetworkEnv, beta: Array, p: Array) -> Array:
         add = p[u] * jnp.outer(cell[u], jax.nn.one_hot(m, env.n_sub))
         return ap_tx + add, m.astype(jnp.int32)
 
-    _, subs = jax.lax.scan(step, jnp.zeros((env.n_aps, env.n_sub)),
-                           jnp.arange(env.n_users))
+    with jax.named_scope("greedy_rounding"):
+        _, subs = jax.lax.scan(step, jnp.zeros((env.n_aps, env.n_sub)),
+                               jnp.arange(env.n_users))
     return subs
 
 

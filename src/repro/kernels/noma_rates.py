@@ -439,6 +439,14 @@ def _segment_table(values: jax.Array, ap: jax.Array, n_aps: int) -> jax.Array:
         values.astype(jnp.float32))
 
 
+def _scope(kernel: str, uplink: bool, direction: str):
+    """The named scope of one NOMA kernel call: the kernel, the link and
+    the pass, e.g. ``noma_intra_up_fwd``. The TPU compiler names the
+    call's custom-call after it, so a device trace tells the calls apart."""
+    return jax.named_scope(
+        f"noma_{kernel}_{'up' if uplink else 'dn'}_{direction}")
+
+
 def noma_pairwise_kernel(
     own_u: jax.Array,    # (U, M) fp32
     own_v: jax.Array,    # (V, M)  V may differ from U (it never does in ops)
@@ -464,22 +472,25 @@ def noma_pairwise_kernel(
     gain-carrying reduction N-tiled and single-pass, the rest O(U*M).
     All inputs are consumed unpadded; boundary blocks are masked in-kernel."""
     tile_u, tile_v = tiles if tiles is not None else (None, None)
-    intra = noma_cell_intra_kernel(
-        own_u, own_v, w_intra, ap_u, ap_v, tile_u, tile_v,
-        descending=descending, block_r=block_u, block_s=block_v,
-        block_m=block_m, interpret=interpret)
+    with _scope("intra", uplink, "fwd"):
+        intra = noma_cell_intra_kernel(
+            own_u, own_v, w_intra, ap_u, ap_v, tile_u, tile_v,
+            descending=descending, block_r=block_u, block_s=block_v,
+            block_m=block_m, interpret=interpret)
     if uplink:
-        a_nm = noma_per_ap_kernel(ap_v, w_power, g_raw, uplink=True,
-                                  block_w=block_v, block_m=block_m,
-                                  block_n=block_n,
-                                  interpret=interpret)
+        with _scope("per_ap", uplink, "fwd"):
+            a_nm = noma_per_ap_kernel(ap_v, w_power, g_raw, uplink=True,
+                                      block_w=block_v, block_m=block_m,
+                                      block_n=block_n,
+                                      interpret=interpret)
         inter = jnp.take(a_nm, ap_u, axis=0)
     else:
         b_nm = _segment_table(w_power, ap_v, g_raw.shape[0])
-        inter = noma_ap_contract_kernel(ap_u, b_nm, g_raw, uplink=False,
-                                        block_w=block_u, block_m=block_m,
-                                        block_n=block_n,
-                                        interpret=interpret)
+        with _scope("contract", uplink, "fwd"):
+            inter = noma_ap_contract_kernel(ap_u, b_nm, g_raw, uplink=False,
+                                            block_w=block_u, block_m=block_m,
+                                            block_n=block_n,
+                                            interpret=interpret)
     return intra, inter
 
 
@@ -516,21 +527,24 @@ def noma_pairwise_bwd_kernel(
     needed because the channel gains are environment constants in the GD
     path."""
     tile_v_b, tile_u_b = tiles if tiles is not None else (None, None)
-    d_wi = noma_cell_intra_kernel(
-        own_v, own_u, d_intra, ap_v, ap_u, tile_v_b, tile_u_b,
-        descending=not descending, block_r=block_v, block_s=block_u,
-        block_m=block_m, interpret=interpret)
+    with _scope("intra", uplink, "bwd"):
+        d_wi = noma_cell_intra_kernel(
+            own_v, own_u, d_intra, ap_v, ap_u, tile_v_b, tile_u_b,
+            descending=not descending, block_r=block_v, block_s=block_u,
+            block_m=block_m, interpret=interpret)
     if uplink:
         c_nm = _segment_table(d_inter, ap_u, g_raw.shape[1])
-        d_wp = noma_ap_contract_kernel(ap_v, c_nm, g_raw, uplink=True,
-                                       block_w=block_v, block_m=block_m,
-                                       block_n=block_n,
-                                       interpret=interpret)
+        with _scope("contract", uplink, "bwd"):
+            d_wp = noma_ap_contract_kernel(ap_v, c_nm, g_raw, uplink=True,
+                                           block_w=block_v, block_m=block_m,
+                                           block_n=block_n,
+                                           interpret=interpret)
     else:
-        d_nm = noma_per_ap_kernel(ap_u, d_inter, g_raw, uplink=False,
-                                  block_w=block_u, block_m=block_m,
-                                  block_n=block_n,
-                                  interpret=interpret)
+        with _scope("per_ap", uplink, "bwd"):
+            d_nm = noma_per_ap_kernel(ap_u, d_inter, g_raw, uplink=False,
+                                      block_w=block_u, block_m=block_m,
+                                      block_n=block_n,
+                                      interpret=interpret)
         d_wp = jnp.take(d_nm, ap_v, axis=0)
     return d_wi, d_wp
 

@@ -1,7 +1,8 @@
 """The closed loop: streams -> batcher -> telemetry -> QoS -> planner.
 
 One epoch of online serving is ONE compiled program (`kind "online_epoch"`
-in planning.compile_log) plus one host decision point:
+in planning.compile_log, XLA module ``jit_epoch``) plus one host decision
+point:
 
   device (compiled, state donated in place):
     1. scenario.step/env      -- mobility + fading advance, env materializes
@@ -19,6 +20,14 @@ in planning.compile_log) plus one host decision point:
     - read the QoS trigger (one scalar sync, the loop's decision point)
     - OnlineSplitServer.observe(env, prof=measured, force=trigger): replan
       on schedule or on trigger; its one sync is s* (the re-cut decision)
+
+Every host read goes through repro.obs.host_read: it runs under the span
+``sync.<name>`` (per epoch ``sync.trigger``, ``sync.plan_word``,
+``sync.health``, ``sync.record``, ``sync.history``; at the end
+``sync.iters``, ``sync.metrics``) and is counted per name in
+``host_reads``, which metrics() reports. The epoch program's call runs
+under the span ``dispatch.epoch``, the server's engine call under
+``dispatch.replan``.
 
 Because the plan enters the epoch program as a SplitPlan operand and the
 measured profile enters the planner as a ModelProfile operand (same avals
@@ -61,7 +70,7 @@ from repro.faults import degrade as degradelib
 from repro.faults import guards, injectors
 from repro.faults.degrade import DegradeLadder, EpochWatchdog, LadderConfig
 from repro.faults.injectors import FaultConfig, FaultState
-from repro.planning.engine import _recorded
+from repro.obs import host_read, recorded, span
 from repro.runtime.serve import OnlineSplitServer
 from repro.online import batcher as batcherlib
 from repro.online.batcher import BatchState, ContinuousBatcher
@@ -157,6 +166,8 @@ class OnlineLoop:
         self.server = OnlineSplitServer(engine, model, params,
                                         replan_every=service_cfg.replan_every,
                                         guard_plans=self._hardened)
+        # host reads by name (repro.obs.host_read), shared with the server
+        self.host_reads = self.server.host_reads
         # episode state (device pytrees), populated by reset()
         self._sc = self._st = self._bt = self._qs = self._tel = None
         self._fs: FaultState | None = None
@@ -287,13 +298,14 @@ class OnlineLoop:
                                            ).astype(jnp.int32))
             return sc, st, bt, qs, tel, fs, out
 
-        # _recorded: each trace of the epoch program logs "online_epoch" to
+        # recorded: each trace of the epoch program logs "online_epoch" to
         # planning.compile_log sinks -- the steady-state compile-once
-        # property is asserted against this, exactly like the engine kinds.
+        # property is asserted against this, exactly like the engine kinds
+        # -- and it lowers as module jit_epoch.
         # The fault rates (arg 2) are NOT donated: the same operand tuple
         # re-enters every epoch (and swapping it is how the benchmark
         # sweeps outage rates without retracing).
-        return jax.jit(_recorded(epoch, "online_epoch"),
+        return jax.jit(recorded(epoch, "online_epoch", name="epoch"),
                        donate_argnums=(3, 4, 5, 6, 7, 8))
 
     # -- episode driving ---------------------------------------------------
@@ -332,7 +344,7 @@ class OnlineLoop:
                 return degradelib.fallback_plan(env, prof, w,
                                                 template=template, mode=mode)
 
-            self._fb_jit = jax.jit(_recorded(fb, "fallback_plan"))
+            self._fb_jit = jax.jit(recorded(fb, "fallback_plan"))
         return self._fb_jit(env)
 
     def reset(self, key: jax.Array) -> None:
@@ -498,11 +510,14 @@ class OnlineLoop:
         return hashlib.sha256(parts.encode()).hexdigest()[:16]
 
     def _step_epoch_inner(self) -> tuple[EpochOut, bool]:
-        (self._sc, self._st, self._bt, self._qs, self._tel, self._fs,
-         out) = self._epoch(self._key, self._plan, self._rates, self._sc,
-                            self._st, self._bt, self._qs, self._tel,
-                            self._fs)
-        trigger = bool(out.report.trigger)   # the per-epoch decision sync
+        with span("dispatch.epoch"):
+            (self._sc, self._st, self._bt, self._qs, self._tel, self._fs,
+             out) = self._epoch(self._key, self._plan, self._rates, self._sc,
+                                self._st, self._bt, self._qs, self._tel,
+                                self._fs)
+        # the per-epoch decision sync
+        trigger = bool(host_read(out.report.trigger, "trigger",
+                                 self.host_reads))
         if self.ladder is None:
             prof = self.measured_profile() if self.feedback else None
             self.server.observe(out.env, prof=prof, force=trigger)
@@ -510,7 +525,8 @@ class OnlineLoop:
             return out, trigger
         # Hardened path: one extra scalar (the packed health word) feeds
         # the ladder; the ladder shapes the replan and the served plan.
-        dec = self.ladder.pre_replan(int(out.health))
+        dec = self.ladder.pre_replan(
+            int(host_read(out.health, "health", self.host_reads)))
         if dec.force_cold:
             self.server.reset_warm()
         prof = (self.measured_profile()
@@ -541,8 +557,10 @@ class OnlineLoop:
                 self.ladder.on_timeout()
         self.host_epoch += 1
         if self._recorder is not None:
+            s, health = host_read((self._plan.s, out.health), "record",
+                                  self.host_reads)
             self._recorder.record_epoch(
-                self.host_epoch, s=int(self._plan.s), health=int(out.health),
+                self.host_epoch, s=int(s), health=int(health),
                 trigger=bool(trigger),
                 stage=self.ladder.stage if self.ladder is not None
                 else "normal")
@@ -576,39 +594,44 @@ class OnlineLoop:
 
     def record_history(self, hist: dict[str, list], out: EpochOut,
                        trigger: bool) -> None:
-        """Append one epoch's host-visible scalars to ``hist``."""
-        hist["s"].append(int(self._plan.s))
-        hist["p50"].append(float(out.report.p50))
-        hist["p95"].append(float(out.report.p95))
-        hist["miss_rate"].append(float(out.report.miss_rate))
-        hist["occupancy"].append(int(out.occupancy))
-        hist["backlog"].append(int(out.backlog))
-        hist["completed"].append(int(out.completed))
-        hist["congestion"].append(float(out.congestion))
+        """Append one epoch's host-visible scalars to ``hist`` (one host
+        read)."""
+        rep = out.report
+        v = host_read({
+            "s": self._plan.s, "p50": rep.p50, "p95": rep.p95,
+            "miss_rate": rep.miss_rate, "occupancy": out.occupancy,
+            "backlog": out.backlog, "completed": out.completed,
+            "congestion": out.congestion, "health": out.health,
+            "faulted": out.faulted,
+            # Was the plan on the air this epoch finite? The chaos
+            # benchmark's "no NaN plans served" gate reads this.
+            "plan_finite": jnp.isfinite(self._plan.utility),
+        }, "history", self.host_reads)
+        for k in ("s", "occupancy", "backlog", "completed", "health",
+                  "faulted"):
+            hist[k].append(int(v[k]))
+        for k in ("p50", "p95", "miss_rate", "congestion"):
+            hist[k].append(float(v[k]))
+        hist["plan_finite"].append(bool(v["plan_finite"]))
         hist["trigger"].append(bool(trigger))
-        hist["health"].append(int(out.health))
-        hist["faulted"].append(int(out.faulted))
-        # Was the plan on the air this epoch finite? The chaos
-        # benchmark's "no NaN plans served" gate reads this.
-        hist["plan_finite"].append(bool(jnp.isfinite(self._plan.utility)))
         hist["stage"].append(self.ladder.stage if self.ladder
                              else "normal")
 
     def metrics(self) -> dict:
-        """End-of-episode summary. Syncs the episode counters once."""
+        """End-of-episode summary, with ``host_reads``: the loop's and the
+        server's host reads so far, by name. Syncs the episode counters
+        once."""
         m = dict(self.server.metrics())
-        m.update({
-            "offered": int(self._st.offered),
-            "completed": int(self._bt.completed),
-            "dropped": int(self._bt.dropped),
-            "shed": int(self._bt.shed),
-            "served": int(self._qs.served),
-            "deadline_missed": int(self._qs.missed),
-            "goodput": int(self._qs.good),
-            "qos_triggers": int(self._qs.triggers),
-            "epochs": int(self._st.epoch),
-            "duration_s": float(self._st.epoch) * self.stream_cfg.epoch_dt_s,
-        })
+        counters = host_read({
+            "offered": self._st.offered, "completed": self._bt.completed,
+            "dropped": self._bt.dropped, "shed": self._bt.shed,
+            "served": self._qs.served, "deadline_missed": self._qs.missed,
+            "goodput": self._qs.good, "qos_triggers": self._qs.triggers,
+            "epochs": self._st.epoch,
+        }, "metrics", self.host_reads)
+        m.update({k: int(v) for k, v in counters.items()})
+        m["duration_s"] = m["epochs"] * self.stream_cfg.epoch_dt_s
+        m["host_reads"] = dict(self.host_reads)
         dur = max(m["duration_s"], 1e-9)
         m["requests_per_s"] = m["completed"] / dur
         m["offered_per_s"] = m["offered"] / dur

@@ -1,11 +1,11 @@
 """Unified planning stack: PlannerEngine over static, batched, and
 time-correlated (online warm-start) environments -- vmapped on one device
 or shard_map-sharded over a fleet mesh (see repro.pshard.fleet_mesh)."""
+from repro.obs import compile_log  # noqa: F401
 from repro.planning.engine import (  # noqa: F401
     PlannerEngine,
     PlanState,
     WarmStateShapeError,
-    compile_log,
     member,
     stack_envs,
 )

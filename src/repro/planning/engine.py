@@ -47,7 +47,6 @@ sweep.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 from typing import NamedTuple, Sequence
@@ -66,6 +65,7 @@ from repro.core.types import (
     SplitPlan,
     make_weights,
 )
+from repro.obs import recorded
 from repro.pshard import axis_size, fleet_axis
 
 
@@ -75,47 +75,6 @@ def _shard_map(fn, *, mesh, in_specs, out_specs):
     is fully fleet-sharded anyway."""
     return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-
-
-# -- compile observability --------------------------------------------------
-# Every solver program the engine jits is wrapped so that each TRACE (which
-# is exactly each compilation: jax.jit re-runs the python body only when the
-# signature cache misses) appends its entry-point kind to the active logs.
-# This is what makes "replan compiled exactly once across cold->warm->cold"
-# machine-checkable (repro.analysis probes + the recompile regression test)
-# instead of an assumption about the PR 3 weak-type fix.
-_COMPILE_LOGS: list[list[str]] = []
-
-
-@contextlib.contextmanager
-def compile_log():
-    """Record the kind of every engine program traced inside the block:
-
-        with compile_log() as log:
-            eng.plan(env); eng.replan(state, env)
-        assert log == ["plan", "replan"]
-
-    Entries appear at trace time, so a steady-state loop that appends
-    nothing proves zero recompiles. Nesting is fine (each context gets its
-    own list); tracing-only inspection (engine.program + jax.make_jaxpr /
-    jax.eval_shape) also records, so keep audit traffic outside the block
-    when counting execution compiles."""
-    sink: list[str] = []
-    _COMPILE_LOGS.append(sink)
-    try:
-        yield sink
-    finally:
-        _COMPILE_LOGS.remove(sink)
-
-
-def _recorded(fn, kind: str):
-    """Wrap a to-be-jitted solver program so each trace logs its kind."""
-    @functools.wraps(fn)
-    def wrapped(*args):
-        for sink in _COMPILE_LOGS:
-            sink.append(kind)
-        return fn(*args)
-    return wrapped
 
 
 class WarmStateShapeError(ValueError):
@@ -370,19 +329,19 @@ class PlannerEngine:
                 rounding=self.rounding, warm_rho_min=self.warm_rho_min,
                 warm_moment_decay=self.warm_moment_decay)
             if kind == "plan":
-                fn = jax.jit(_recorded(solve, kind))
+                fn = jax.jit(recorded(solve, kind))
             elif kind == "plan_many":
-                fn = jax.jit(_recorded(
+                fn = jax.jit(recorded(
                     jax.vmap(solve, in_axes=(0, None, None)), kind))
             elif kind == "replan":
-                fn = jax.jit(_recorded(resolve, kind))
+                fn = jax.jit(recorded(resolve, kind))
             elif kind == "replan_many":
-                fn = jax.jit(_recorded(
+                fn = jax.jit(recorded(
                     jax.vmap(resolve, in_axes=(0, None, None, 0, 0, 0, 0)),
                     kind))
             elif kind == "plan_many_sharded":
                 ax = fleet_axis(self.mesh)
-                fn = jax.jit(_recorded(_shard_map(
+                fn = jax.jit(recorded(_shard_map(
                     jax.vmap(solve, in_axes=(0, None, None)), mesh=self.mesh,
                     in_specs=(P(ax), P(), P()), out_specs=P(ax)), kind))
             elif kind == "replan_many_sharded":
@@ -391,7 +350,7 @@ class PlannerEngine:
                 # caller threads the *returned* PlanState to the next epoch,
                 # so XLA may reuse the previous epoch's buffers in place.
                 fn = jax.jit(
-                    _recorded(_shard_map(
+                    recorded(_shard_map(
                         jax.vmap(resolve, in_axes=(0, None, None, 0, 0, 0, 0)),
                         mesh=self.mesh,
                         in_specs=(P(ax), P(), P(), P(ax), P(ax), P(ax), P(ax)),

@@ -9,6 +9,7 @@ the NOMA rate model and `transfer_seconds` reports the simulated link time.
 """
 from __future__ import annotations
 
+import collections
 import functools
 from typing import NamedTuple
 
@@ -18,6 +19,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.models import Model
 from repro.models.layers import COMPUTE_DTYPE, embed_lookup, logits_out
+from repro.obs import host_read, recorded, span
 from repro.planning import WarmStateShapeError
 from repro.runtime import sharding as shlib
 
@@ -196,7 +198,9 @@ class OnlineSplitServer:
     program), GD-iteration accounting accumulates in a device scalar (read
     it lazily via the `total_iters` property), and the only host sync per
     replan is fetching the planned split layer s* -- the serve decision that
-    chooses whether to re-cut the model is inherently a host branch.
+    chooses whether to re-cut the model is inherently a host branch. That
+    read runs under the span ``sync.plan_word`` and the engine call under
+    ``dispatch.replan``; ``host_reads`` counts the server's reads by name.
 
     model/params may be None for planning-only runs (benchmarks, tests):
     the re-cut is then recorded but no programs are built.
@@ -241,12 +245,13 @@ class OnlineSplitServer:
         self.last_replanned = False     # did the last observe() dispatch?
         self._iters_acc = jnp.zeros((), jnp.int32)  # device-side accumulator
         self._plan_word_fn = None       # jitted guard, built on first use
+        self.host_reads = collections.Counter()   # repro.obs.host_read
 
     @property
     def total_iters(self) -> int:
         """Total GD iterations across all re-plans. Reading it syncs the
         device accumulator; the serving loop itself never does."""
-        return int(self._iters_acc)
+        return int(host_read(self._iters_acc, "iters", self.host_reads))
 
     def metrics(self) -> dict:
         """Counters of the server's control-plane activity: epochs seen,
@@ -317,16 +322,17 @@ class OnlineSplitServer:
         guard program is jitted once per server (env consts are closures,
         the plan is an operand -- no cache growth across epochs)."""
         if not self.guard_plans:
-            return 0, int(plan.s)
+            return 0, int(host_read(plan.s, "plan_word", self.host_reads))
         if self._plan_word_fn is None:
             from repro.faults import guards
-            from repro.planning.engine import _recorded
-            self._plan_word_fn = jax.jit(_recorded(functools.partial(
+            self._plan_word_fn = jax.jit(recorded(functools.partial(
                 guards.plan_word, n_sub=env.n_sub,
                 p_up_max=env.radio.p_up_max_w, p_dn_max=env.radio.p_dn_max_w,
                 r_max=env.comp.r_max), "plan_guard"))
         from repro.faults.guards import split_plan_word
-        return split_plan_word(int(self._plan_word_fn(plan)))
+        word = host_read(self._plan_word_fn(plan), "plan_word",
+                         self.host_reads)
+        return split_plan_word(int(word))
 
     def observe(self, env, prof=None, force: bool = False,
                 hold: bool = False) -> SplitPrograms | None:
@@ -341,7 +347,8 @@ class OnlineSplitServer:
         if not hold and (force or self.epoch % self.replan_every == 0):
             prev_state = self.state
             try:
-                new_state = self.engine.replan(self.state, env, prof=prof)
+                with span("dispatch.replan"):
+                    new_state = self.engine.replan(self.state, env, prof=prof)
             except WarmStateShapeError:
                 # Shape change: the warm-start state no longer fits this
                 # network. Reset it and fall back to a cold plan. (Other
@@ -349,7 +356,8 @@ class OnlineSplitServer:
                 # disable warm starts forever.)
                 prev_state = self.state = None
                 self.cold_resets += 1
-                new_state = self.engine.plan(env, prof=prof)
+                with span("dispatch.replan"):
+                    new_state = self.engine.plan(env, prof=prof)
             self.replans += 1
             self.last_replanned = True
             self.forced_replans += int(

@@ -6,7 +6,8 @@ too much SMEM or VMEM -- so the interpret-mode parity tests cannot show the
 kernels run on the chip. These tests compile the NOMA pairwise kernels,
 forward and backward, at the paper's scale (U = 1250, M = 250) for a small
 and a massive AP count, plus one pallas-backend Li-GD step, and check the
-kernel made it into the program (``tpu_custom_call``).
+kernel made it into the program (``tpu_custom_call``). A small replan
+program shows what its named scopes leave in the compiled program.
 
 The topology is described inside a module fixture (never at import time or
 in ``parametrize``): only one process may load the TPU library, and under
@@ -15,6 +16,7 @@ compilation cache is off around these compiles, since an entry written
 without a chip cannot be read back.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -122,3 +124,45 @@ def test_gd_solve_step_compiles_for_v5e(one_chip):
     env = _env_shapes(one_chip, 16)
     w = _on(one_chip, jax.eval_shape(lambda: make_weights(U)))
     assert "tpu_custom_call" in _compile_text(step, env, w)
+
+
+@pytest.fixture(scope="module")
+def replan_text(one_chip):
+    """The optimized v5e HLO of the planner's replan program on the Pallas
+    path, at a small cell (U=24, N=3, M=8)."""
+    from repro.planning import PlannerEngine
+
+    u, n, m = 24, 3, 8
+    env = jax.eval_shape(lambda k: make_env(k, u, n, m),
+                         jax.random.PRNGKey(0))
+    eng = PlannerEngine(profiles.vgg16(), weights=make_weights(u),
+                        cfg=GdConfig(max_iters=2, optimizer="adam"),
+                        sinr_backend="pallas")
+    cold = jax.eval_shape(eng.program("plan", env),
+                          *eng.program_args("plan", env))
+    args = _on(one_chip, eng.program_args("replan", env, prev=cold))
+    lowered = eng.program("replan", env).lower(*args)
+    assert lowered.as_text().startswith("module @jit_replan")
+    return lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("scope", [
+    "noma_intra_up_fwd", "noma_intra_dn_fwd", "noma_per_ap_up_fwd",
+    "noma_contract_dn_fwd", "noma_intra_up_bwd", "noma_intra_dn_bwd",
+    "noma_contract_up_bwd", "noma_per_ap_dn_bwd"])
+def test_kernel_calls_are_named_by_their_scope(replan_text, scope):
+    """The TPU compiler names each NOMA kernel's custom call after its
+    innermost named scope (kernel, link, pass), so the device trace's op
+    events tell the calls apart."""
+    calls = re.findall(r"^\s*(?:ROOT )?%(\S+) = [^\n]*custom-call\(",
+                       replan_text, re.M)
+    assert any(scope in c for c in calls)
+
+
+@pytest.mark.parametrize("scope", ["gd_iter", "warm_gate", "greedy_rounding"])
+def test_solver_phases_reach_the_op_metadata(replan_text, scope):
+    """Fusions and loops keep their generic names; the solver's phase
+    scopes reach every op's name stack (op_name), which the trace carries
+    as each op's tf_op."""
+    assert re.search(rf'op_name="jit\(replan\)/[^"]*\b{scope}/',
+                     replan_text)
