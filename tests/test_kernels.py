@@ -1,4 +1,6 @@
 """Per-kernel shape/dtype sweeps vs the pure-jnp oracles (interpret mode)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -228,6 +230,45 @@ def test_noma_gather_free_single_cell_inter_is_exactly_zero(uplink,
     _, inter = _run_pairwise(env, own, w_intra, tx, uplink, uplink,
                              with_layout, 8, 8, 8, 8)
     np.testing.assert_array_equal(np.asarray(inter), 0.0)
+
+
+_SMALL_BLOCKS = dict(block_u=8, block_v=8, block_m=128, block_n=8)
+
+
+def _rel_gap(got, want):
+    got, want = np.asarray(got), np.asarray(want)
+    return np.max(np.abs(got - want)) / max(np.abs(want).max(), 1e-30)
+
+
+@pytest.mark.parametrize("u,n,m", [
+    (140, 5, 260),   # U and M past one default block, multiples of neither
+    (20, 3, 6),      # every default block larger than its extent
+])
+@pytest.mark.parametrize("link", ["up", "dn"])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_default_blocks_match_small_block_schedule(u, n, m, link, direction):
+    """DEFAULT_BLOCKS against the (8, 8, 128, 8) schedule, forward (intra,
+    inter) and the VJP of each through the custom_vjp. The intra kernel
+    sums over the streamed users in order whatever the blocks, so its
+    terms are equal bit for bit. The inter terms may differ in the last
+    bit: the backward gain kernels sum each block of APs or users as a
+    tree, and XLA's CPU backend fuses the gain kernels' multiply-adds by
+    block shape, so their gap is bounded at 1e-6 of the largest term."""
+    env, tx, _, _ = _gather_free_case(u, n, m, seed=u)
+    pairwise = ops.noma_pairwise_up if link == "up" else ops.noma_pairwise_dn
+
+    def run(blocks):
+        f = functools.partial(pairwise, env, interpret=True, **blocks)
+        if direction == "fwd":
+            return f(tx)
+        out, vjp = jax.vjp(f, tx)
+        ct = jax.random.uniform(jax.random.PRNGKey(u + 1), out[0].shape)
+        zeros = jnp.zeros_like(ct)
+        return vjp((ct, zeros))[0], vjp((zeros, ct))[0]
+
+    (intra, inter), (intra_s, inter_s) = run({}), run(_SMALL_BLOCKS)
+    np.testing.assert_array_equal(np.asarray(intra), np.asarray(intra_s))
+    assert _rel_gap(inter, inter_s) <= 1e-6
 
 
 def test_autotune_candidates_fit_vmem_ceiling():
