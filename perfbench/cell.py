@@ -12,6 +12,43 @@ The window runs whole epochs (``OnlineLoop.step_epoch``, no recording) and
 ends at the first epoch boundary after ``seconds``. An epoch ends when its
 health word and the served plan are ready on the device; both are only
 waited for, and the health words are read after the window.
+
+What a configuration brings. Its file's ``"reference"`` key names a module
+(a path relative to the checkout, ``spec.config_module``). That module
+gives:
+
+``profile(model)``            required: the reference's (fl (F,), w (F+1,),
+                              m_down (F+1,)) from the configuration's
+                              ``model`` section. It imports nothing of
+                              ``repro``. The planner's own profile is the
+                              ``repro.core.profiles`` function that
+                              ``model.profile`` names, called with the
+                              keyword arguments ``model.profile_args``
+                              (none where the key is absent).
+``build_loop(cfg, traffic, engine, build)``
+                              optional, the one seam for a served model:
+                              returns the ``OnlineLoop`` to run. ``build``
+                              is :func:`build`, whose keyword arguments
+                              reach ``OnlineLoop`` (``model=``,
+                              ``params=``), so the hook builds the served
+                              model from its configuration's ``served``
+                              section and hands it over. Without the hook
+                              the loop is ``build(cfg, traffic, engine)``.
+``served_checks(cfg, loop)``  optional: called once the window has closed
+                              and the peak memory has been read, before
+                              the loop is freed; returns {name: {"value",
+                              "limit"}}, merged into the run's checks, so
+                              each decides ``correct`` and is printed
+                              beside its limit. A name the planner's checks
+                              already use stays the planner's, and a hook
+                              that raises fails the run.
+``tiny(cfg)``                 optional: the configuration cut for the CPU
+                              rehearsal (``rehearse.tiny``), after its
+                              deployment has been cut.
+
+A metric reader reads :class:`RunData`: besides the host clock it holds
+the program's own episode counters over the window (``counters``) and,
+in a traced run, each device operation's name stack (``scopes``).
 """
 from __future__ import annotations
 
@@ -27,6 +64,7 @@ from typing import NamedTuple
 import numpy as np
 
 from perfbench import check as checklib
+from perfbench import spans
 from perfbench import spec as speclib
 from perfbench import trace as tracelib
 from perfbench import work
@@ -88,9 +126,11 @@ class RunData(NamedTuple):
     epochs: int
     replans: int                 # engine replans dispatched in the window
     gd_iters: int                # GD iterations of those replans (counter)
+    counters: dict[str, int]     # the loop's episode counters over the window
     sizes: work.Sizes
     peak: work.Peak | None
     trace: tracelib.Trace | None
+    scopes: dict[str, str] | None  # traced: op (HLO text) -> its name stack
 
 
 class Result(NamedTuple):
@@ -113,23 +153,24 @@ def key_from_seed(seed: int):
                               (seed >> 32) & 0xFFFFFFFF)
 
 
-def build(cfg: dict, traffic: dict, engine=None):
+def build(cfg: dict, traffic: dict, engine=None, **loop_kwargs):
     """The closed loop of one configuration under one traffic mix: the
     engine, scenario, request stream and service exactly as the files
     state them. ``engine`` reuses an engine built here before, with its
-    compiled programs."""
+    compiled programs; ``loop_kwargs`` go to ``OnlineLoop``."""
     from repro.core import GdConfig, make_weights, profiles
     from repro.core.types import ComputeConstants, RadioConstants
     from repro.online import OnlineLoop, ServiceConfig, StreamConfig
     from repro.planning import PlannerEngine
     from repro.scenarios import Scenario, ScenarioConfig
 
-    dep, planner = cfg["deployment"], cfg["planner"]
+    dep, planner, model = cfg["deployment"], cfg["planner"], cfg["model"]
     radio = RadioConstants(**cfg["radio"])
     comp = ComputeConstants(**cfg["compute"])
     if engine is None:
         engine = PlannerEngine(
-            getattr(profiles, cfg["model"]["profile"])(),
+            getattr(profiles, model["profile"])(
+                **model.get("profile_args", {})),
             weights=make_weights(dep["n_users"], cfg["model"]["w_T"]),
             cfg=GdConfig(**planner["gd"]), method=planner["method"],
             rounding=planner["rounding"],
@@ -141,7 +182,37 @@ def build(cfg: dict, traffic: dict, engine=None):
         n_sub=dep["n_sub"], radio=radio, comp=comp, **traffic["scenario"]))
     return OnlineLoop(scenario, engine, StreamConfig(**traffic["stream"]),
                       ServiceConfig(**traffic["service"]),
-                      feedback=traffic["feedback"])
+                      feedback=traffic["feedback"], **loop_kwargs)
+
+
+def make_loop(module, cfg: dict, traffic: dict, engine=None):
+    """The loop a run drives: the configuration module's ``build_loop``
+    hook where it has one, else :func:`build`."""
+    hook = getattr(module, "build_loop", None)
+    if hook is None:
+        return build(cfg, traffic, engine)
+    return hook(cfg, traffic, engine, build)
+
+
+def counters(loop) -> dict[str, int]:
+    """The integer entries of ``OnlineLoop.metrics()``: the episode
+    counters (replans, GD iterations, requests offered, served, ...). The
+    read syncs the device."""
+    return {k: v for k, v in loop.metrics().items()
+            if isinstance(v, int) and not isinstance(v, bool)}
+
+
+def _served_checks(module, cfg: dict, loop) -> dict[str, dict]:
+    """The configuration module's ``served_checks``; a hook that raises
+    fails the run through the check ``served_checks_raised``."""
+    hook = getattr(module, "served_checks", None)
+    if hook is None:
+        return {}
+    try:
+        return dict(hook(cfg, loop))
+    except Exception:                   # the hook's fault fails the run
+        traceback.print_exc()
+        return {"served_checks_raised": {"value": 1, "limit": 0}}
 
 
 def _replan_record(recorder: Recorder) -> tuple[dict, dict, dict]:
@@ -190,16 +261,19 @@ def run(cell: speclib.Cell, seed: int, seconds: float, trace: bool,
     notes: list[str] = []
 
     # -- set-up --------------------------------------------------------------
-    loop = build(cfg, traffic, engine)
+    loop = make_loop(cell.module, cfg, traffic, engine)
     recorder = Recorder(loop.server.engine)
     loop.server.engine = recorder
     loop.reset(key_from_seed(seed))
     for _ in range(traffic["service"]["replan_every"]):
         out, _ = loop.step_epoch()
         jax.block_until_ready((out.health, loop.server.state.plan.utility))
-    iters0, replans0 = loop.server.total_iters, loop.server.replans
-    forced0 = loop.server.forced_replans
+    counts0, reads0 = counters(loop), dict(loop.host_reads)
     compile_s, cache_hits = meter.compile_s, meter.cache_hits
+    # Set-up's garbage (traced programs, lowered modules) is collected here,
+    # so that no full collection of it stalls an epoch of the window.
+    gc.collect()
+    gc0 = [g["collections"] for g in gc.get_stats()]
 
     # -- the window ----------------------------------------------------------
     trace_dir = tempfile.mkdtemp(prefix="perfbench-trace-") if trace else None
@@ -231,34 +305,46 @@ def run(cell: speclib.Cell, seed: int, seconds: float, trace: bool,
             jax.profiler.stop_trace()
     t_stop = time.perf_counter()
     compiled = meter.compiles - compiles0
+    gcs = [g["collections"] - n for g, n in zip(gc.get_stats(), gc0)]
 
     # -- after the window: counters, health, memory ---------------------------
     setup_s = t0 - t_start
-    gd_iters = loop.server.total_iters - iters0
-    replans = loop.server.replans - replans0
-    forced = loop.server.forced_replans - forced0
+    reads1 = dict(loop.host_reads)
+    counts = {k: v - counts0.get(k, 0) for k, v in counters(loop).items()}
+    counts.update({f"host_reads.{k}": v - reads0.get(k, 0)
+                   for k, v in reads1.items()})
+    gd_iters, replans = counts["total_iters"], counts["replans"]
     bad = sum(int(h) != 0 for h in jax.device_get(healths))
     stats = devices[0].memory_stats() or {}
+    served = _served_checks(cell.module, cfg, loop)
     env, prev, out_h = _replan_record(recorder)
     notes.append(
         f"setup: compile_s={compile_s!r} cache_hits={cache_hits} "
         f"setup_s={setup_s!r}")
     notes.append(
-        f"window: epochs={len(epoch_s)} replans={replans} forced={forced} "
+        f"window: epochs={len(epoch_s)} replans={replans} "
+        f"forced={counts['forced_replans']} "
         f"gd_iters={gd_iters} unhealthy={bad} raised={raised} "
         f"compile_events={compiled} traced_programs={list(traced)} "
         f"peak_bytes_in_use={stats.get('peak_bytes_in_use')} "
-        f"plan_s={int(out_h['s'])}")
+        f"plan_s={int(out_h['s'])} gc_collections={gcs}")
+    if epoch_s:
+        ms = sorted(1e3 * t for t in epoch_s)
+        at = sorted(range(len(epoch_s)), key=epoch_s.__getitem__)[:-6:-1]
+        notes.append(
+            f"epoch_wall_ms: min={ms[0]!r} median={float(np.median(ms))!r} "
+            f"max={ms[-1]!r} largest={ms[:-6:-1]!r} largest_at={at}")
     del loop, recorder, out, healths
     gc.collect()
 
     dep = cfg["deployment"]
     z = work.sizes(dep["n_users"], dep["n_aps"], dep["n_sub"], env["ap"])
-    tr = None
+    tr = scopes = None
     if trace_dir:
         t_read = time.perf_counter()
         try:
-            tr = tracelib.load(tracelib.find_xplane(trace_dir))
+            path = tracelib.find_xplane(trace_dir)
+            tr, scopes = tracelib.load(path), spans.op_scopes(path)
         finally:
             shutil.rmtree(trace_dir, ignore_errors=True)
         notes.append(f"trace: stop_s={t_stop - t1!r} "
@@ -275,7 +361,8 @@ def run(cell: speclib.Cell, seed: int, seconds: float, trace: bool,
           if devices[0].platform == "tpu" else None)
     data = RunData(cell=cell, setup_s=setup_s, window_s=t1 - t0,
                    epoch_s=epoch_s, epochs=len(epoch_s), replans=replans,
-                   gd_iters=gd_iters, sizes=z, peak=pk, trace=tr)
+                   gd_iters=gd_iters, counters=counts, sizes=z, peak=pk,
+                   trace=tr, scopes=scopes)
 
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
@@ -294,9 +381,11 @@ def run(cell: speclib.Cell, seed: int, seconds: float, trace: bool,
                      "idle_gaps": tracelib.idle_gaps(tr)}
 
     # -- correctness -----------------------------------------------------------
-    checks = checklib.check(cfg, env, prev, out_h, control=control)
+    checks = checklib.check(cfg, cell.module, env, prev, out_h,
+                            control=control)
     checks["unhealthy_epochs"] = {"value": bad + raised, "limit": 0}
     checks["window_compiles"] = {"value": compiled + len(traced), "limit": 0}
+    checks.update({k: v for k, v in served.items() if k not in checks})
     return Result(correct=checklib.passed(checks), attempted=len(epoch_s) + raised,
                   failed=bad + raised, metrics=metrics, device=device,
                   breakdown=breakdown, checks=checks, notes=notes)

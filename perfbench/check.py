@@ -34,6 +34,12 @@ and its result, copied off the device once the window has closed):
 The control is the reference put in the program's place on inputs rounded
 to bfloat16: the channel gains, the previous epoch's state and the chain
 carries. It must fail at least one number.
+
+The planner's reference (``perfbench/reference.py``: the NOMA rates, the
+utility, the Li-GD replan and the roundings) is shared by every
+configuration. The model's part, its profile, comes from the module the
+configuration names (``spec.Cell.module``): its ``profile(model)`` gives
+(fl, w, m_down) from the configuration's ``model`` section.
 """
 from __future__ import annotations
 
@@ -86,15 +92,17 @@ def _host(tree):
 
 
 class Setting:
-    """The reference's view of one configuration."""
+    """The reference's view of one configuration, its model profile from
+    the configuration's module ``model``."""
 
-    def __init__(self, cfg: dict):
+    def __init__(self, cfg: dict, model):
         planner = cfg["planner"]
         self.c = ref.consts(cfg)
         self.gd = dict(planner["gd"])
         self.rho_min = planner["warm_rho_min"]
         self.decay = planner["warm_moment_decay"]
-        self.prof = tuple(np.asarray(a) for a in ref.profile(cfg["model"]))
+        self.prof = tuple(np.asarray(a, np.float32)
+                          for a in model.profile(cfg["model"]))
         self.limits = cfg["limits"]
 
 
@@ -147,14 +155,15 @@ def _solve(st: Setting, net, prev: dict, carries: dict):
                                st.decay, prev, carries)
 
 
-def check(cfg: dict, env: dict, prev: dict, out: dict,
+def check(cfg: dict, model, env: dict, prev: dict, out: dict,
           control: bool = False) -> dict[str, dict]:
     """Compare a replan's result ``out`` with the reference run on its
     operands (``env``: g_up, g_dn, ap; ``prev``: norms, m1, m2, steps,
-    gains). Returns {name: {"value", "limit"}} for the program and, with
+    gains), the model's profile from the configuration's module ``model``.
+    Returns {name: {"value", "limit"}} for the program and, with
     ``control``, also {"control." + name: ...} for the bfloat16 control."""
     import jax.numpy as jnp
-    st = Setting(cfg)
+    st = Setting(cfg, model)
     net = _net(_to_device(env))
     prev_d = _to_device(prev)
     carries = _to_device(out["norms"])

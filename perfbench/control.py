@@ -40,7 +40,7 @@ def main(argv=None) -> int:
     from perfbench import spec
 
     cell = spec.cell(args.workload)
-    engine = celllib.build(cell.config, cell.traffic).engine
+    engine = celllib.make_loop(cell.module, cell.config, cell.traffic).engine
     low, high = {}, {}
     for seed in (int(s) for s in args.seeds.split(",")):
         r = celllib.run(cell, seed, args.seconds, False, time.perf_counter(),

@@ -14,9 +14,10 @@ epochs, replans and GD iterations of the traced epochs and the breakdown of
 :func:`perfbench.spans.report`. It checks nothing, and exits non-zero
 where JAX finds no TPU.
 
-It exists because ``run.py`` deletes its trace before the readers run, so
-no reader sees the name stacks of the ops (``spans.op_scopes``) that the
-solver-phase split needs.
+``run.py``'s traced run gives the per-layer metrics and deletes its trace;
+this keeps the trace for a look by hand, and its breakdown has what no
+metric gives: the idle time by host span at both ends of the clock-offset
+interval, and each kernel's calls.
 """
 import argparse
 import copy
@@ -59,7 +60,7 @@ def main(argv=None) -> int:
         cfg["deployment"] = dict(rehearse.TINY_DEPLOYMENT)
     jax.config.update("jax_default_matmul_precision",
                       cfg["precision"]["matmul_precision"])
-    loop = celllib.build(cfg, cell.traffic)
+    loop = celllib.make_loop(cell.module, cfg, cell.traffic)
     loop.reset(celllib.key_from_seed(args.seed))
     for _ in range(cell.traffic["service"]["replan_every"]):
         out, _ = loop.step_epoch()

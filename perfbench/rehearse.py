@@ -26,12 +26,15 @@ TINY_DEPLOYMENT = {"n_users": 24, "n_aps": 3, "n_sub": 8}
 
 def tiny(cell):
     """``cell`` (a ``spec.Cell``) cut to :data:`TINY_DEPLOYMENT`, with the
-    Pallas kernels run by the interpreter; everything else as its files
-    state it."""
+    Pallas kernels run by the interpreter, then by its configuration
+    module's ``tiny(cfg)`` where it has one (a model cut as well);
+    everything else as its files state it."""
     cfg = copy.deepcopy(cell.config)
     cfg["deployment"] = dict(TINY_DEPLOYMENT)
     if cfg["planner"]["sinr_backend"] == "pallas":
         cfg["planner"]["sinr_backend"] = "pallas_interpret"
+    if hasattr(cell.module, "tiny"):
+        cfg = cell.module.tiny(cfg)
     return cell._replace(config=cfg)
 
 

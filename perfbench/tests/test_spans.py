@@ -207,12 +207,12 @@ def test_name_stacks_of_a_recorded_tpu_trace():
     assert all(s.startswith("jit(<lambda>)/") for s in stacks.values())
 
 
-def _run(t, epochs=2, replans=1):
+def _run(t, epochs=2, replans=1, scopes=None, gd_iters=49):
     c = spec.cell("vgg16-einsum.replan10")
     return cell.RunData(cell=c, setup_s=1.0, window_s=1e-5,
                         epoch_s=[5e-6] * epochs, epochs=epochs,
-                        replans=replans, gd_iters=49, sizes=None, peak=None,
-                        trace=t)
+                        replans=replans, gd_iters=gd_iters, counters={},
+                        sizes=None, peak=None, trace=t, scopes=scopes)
 
 
 @pytest.mark.parametrize("metric, want", [
@@ -226,7 +226,9 @@ def test_readers_on_the_synthetic_trace(metric, want):
 
 
 @pytest.mark.parametrize("metric", ["replan_program_ms",
-                                    "host_syncs_per_epoch", "sync_idle_ms"])
+                                    "host_syncs_per_epoch", "sync_idle_ms",
+                                    "gd_step_ms", "warm_gate_ms",
+                                    "rounding_ms"])
 def test_readers_find_nothing_without_the_program_marks(metric):
     """The parent's programs lower as jit_wrapped and open no spans: the
     readers return nothing, and nothing raises."""
@@ -301,3 +303,25 @@ def test_the_chip_trace_holds_the_program_spans(chip):
     assert spans.sync_count(t) == epochs + REPLANS[cell]
     off = spans.clock_offset(t)
     assert off is not None and off.lo <= off.hi
+
+
+# A reader's scope: (scope, per GD iteration or per replan).
+SCOPE_READERS = {"gd_step_ms": ("gd_iter", "iters"),
+                 "warm_gate_ms": ("warm_gate", "replans"),
+                 "rounding_ms": ("greedy_rounding", "replans")}
+
+
+@pytest.mark.parametrize("metric", sorted(SCOPE_READERS))
+def test_scope_readers_on_the_chip_trace(chip, metric):
+    """Each reads the device time under its scope from the run's name
+    stacks, per GD iteration or per replan."""
+    cell, t, stacks = chip
+    scope, per = SCOPE_READERS[metric]
+    replans = REPLANS[cell]
+    iters = 49 * replans
+    read = spec.reader(spec.ROOT, metric)
+    got = read(_run(t, replans=replans, scopes=stacks, gd_iters=iters))
+    want = spans.scope_seconds(t, stacks, scope) * 1e3 / (
+        iters if per == "iters" else replans)
+    assert want > 0 and got == pytest.approx(want)
+    assert read(_run(t, replans=replans, scopes={}, gd_iters=iters)) is None
