@@ -7,4 +7,4 @@ from repro.configs.base import (  # noqa: F401
     get,
     shape_applicable,
 )
-import repro.configs.archs  # noqa: F401  (registers the 10 assigned archs)
+import repro.configs.archs  # noqa: F401  (registers the assigned archs)

@@ -1,4 +1,4 @@
-"""The 10 assigned architectures, exact configs from the assignment table.
+"""The assigned architectures, exact configs from the assignment table.
 
 [source; verified-tier] tags are recorded next to each config.
 """
@@ -110,4 +110,20 @@ def whisper_small() -> ArchConfig:
         n_layers=12, d_model=768, n_heads=12, n_kv_heads=12,
         d_ff=3072, vocab_size=51865, act="gelu",
         encoder_layers=12, frontend_tokens=1500, rope_theta=0.0,
+    )
+
+
+@register
+def trinity_mini() -> ArchConfig:
+    # [hf:arcee-ai/Trinity-Mini config.json (afmoe); catalog] -- sliding
+    # window 2048 with full attention every 4th layer, 2 leading dense
+    # layers, 128 experts top-8 + 1 shared, sigmoid scores normalised over
+    # the top 8 and scaled by 2.826
+    return ArchConfig(
+        name="trinity-mini", family="moe",
+        n_layers=32, d_model=2048, n_heads=32, n_kv_heads=4, head_dim=128,
+        d_ff=6144, vocab_size=200192, rope_theta=1e4, norm_eps=1e-5,
+        n_experts=128, top_k=8, moe_d_ff=1024, n_shared_experts=1,
+        first_dense_layers=2, window=2048, global_attn_every=4,
+        score_func="sigmoid", route_scale=2.826,
     )

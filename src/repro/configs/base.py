@@ -35,9 +35,14 @@ class ArchConfig:
     moe_d_ff: int = 0
     n_shared_experts: int = 0
     first_dense_layers: int = 0    # deepseek: layer 0 is dense
+    score_func: str = "softmax"    # router scores: softmax | sigmoid
+    route_scale: float = 1.0       # gates: top-k scores normalised to 1, times this
+    experts_held: tuple = ()       # (first, stop) of the experts this chip
+                                   # computes; () holds all n_experts
     # --- hybrid / ssm ---
     block_pattern: tuple = ()      # e.g. ("rec", "rec", "attn") tiled over depth
     window: int = 0                # local attention window (0 = full)
+    global_attn_every: int = 0     # with window: every k-th layer attends fully
     conv_width: int = 4            # RG-LRU temporal conv width
     rglru_dim: int = 0             # lru width (0 -> d_model)
     # --- enc-dec / vlm ---
@@ -52,6 +57,16 @@ class ArchConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def held(self) -> tuple[int, int]:
+        """(first, stop) of the experts whose weights this chip holds."""
+        return tuple(self.experts_held) or (0, self.n_experts)
+
+    def layer_window(self, i: int) -> int:
+        """Attention window of layer ``i`` (0 = full attention)."""
+        every = self.global_attn_every
+        return 0 if every and (i + 1) % every == 0 else self.window
 
     def reduced(self) -> "ArchConfig":
         """Tiny same-topology variant for CPU smoke tests."""
@@ -77,6 +92,7 @@ class ArchConfig:
             cross_attn_every=2 if self.cross_attn_every else 0,
             frontend_tokens=16,
             first_dense_layers=min(self.first_dense_layers, 1),
+            experts_held=(),
         )
 
 

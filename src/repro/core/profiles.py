@@ -3,8 +3,9 @@
 Three sources:
   * chain CNNs the paper evaluates (NiN-9, YOLOv2-17, VGG16-24), built from
     real conv arithmetic (MACs, feature-map sizes) on CIFAR-scale inputs;
-  * any assigned LM architecture config (per-transformer-block profile), so
-    the ECC planner applies to all 10 assigned archs (DESIGN.md Sec. 5);
+  * any assigned LM architecture config (per layer, priced by the layer's
+    kind: window or full attention, dense or MoE FFN), so the ECC planner
+    applies to every assigned arch (DESIGN.md Sec. 5);
   * *measured* profiles produced by the closed-loop serving telemetry
     (repro.online.telemetry): EMA-smoothed effective per-layer costs under
     live traffic, rebuilt every feedback epoch via ``ModelProfile.like`` so
@@ -132,46 +133,65 @@ PAPER_MODELS = {"nin": nin, "yolov2": yolov2, "vgg16": vgg16}
 
 
 # --------------------------------------------------------------------------
-# LM architecture profiles (per-transformer-block), for the assigned archs
+# LM architecture profiles (per layer, by the layer's kind)
 # --------------------------------------------------------------------------
-def lm_block_flops(cfg, seq: int) -> tuple[float, float]:
-    """(dense_block_flops, moe_block_flops_active) for one token batch of
-    length `seq` through one block. GQA-aware; counts fwd only (inference)."""
-    d = cfg.d_model
-    hd = d // cfg.n_heads
-    kv_dim = cfg.n_kv_heads * hd
-    attn_proj = 2.0 * seq * d * (d + 2 * kv_dim + d)          # q,k,v,o matmuls
-    attn_core = 4.0 * seq * seq * d                            # scores + AV
-    if getattr(cfg, "window", None):
-        w = min(cfg.window, seq)
-        attn_core = 4.0 * seq * w * d
-    if cfg.d_ff > 0:
-        mlp = 6.0 * seq * d * cfg.d_ff                         # SwiGLU: 3 matmuls
-    else:
-        mlp = 0.0
-    moe_mlp = mlp
-    if getattr(cfg, "n_experts", 0):
-        active = cfg.top_k + getattr(cfg, "n_shared_experts", 0)
-        moe_mlp = active * 6.0 * seq * d * cfg.moe_d_ff
-    return attn_proj + attn_core + mlp, attn_proj + attn_core + moe_mlp
+TOKEN_BITS = 32        # token ids travel as int32
 
 
-def from_arch_config(cfg, seq: int, batch: int = 1) -> ModelProfile:
-    """Per-block profile of an assigned LM arch: fl[i] = FLOPs of block i,
-    w[s] = bits of the residual-stream activation crossing the split."""
-    dense_f, moe_f = lm_block_flops(cfg, seq)
-    n = cfg.n_layers
-    fl = np.empty(n)
-    for i in range(n):
-        is_moe = bool(getattr(cfg, "n_experts", 0)) and (
-            i % max(1, getattr(cfg, "moe_every", 1)) == 0
-        )
-        fl[i] = (moe_f if is_moe else dense_f) * batch
-    act_bits = batch * seq * cfg.d_model * ACT_BITS
-    w = np.full(n + 1, float(act_bits))
-    w[0] = batch * seq * 32.0  # raw token ids
+def _keys(seq: int, decode: int, window: int) -> float:
+    """Query-key pairs one attention layer scores for a request: a prefill
+    of ``seq`` queries over ``min(seq, window)`` keys each (the whole
+    prompt's scores, as the chunked kernel computes them), then ``decode``
+    steps, step t over the ``seq + t + 1`` keys of its context, at most
+    ``window`` (0 = no window)."""
+    ctx = np.arange(seq + 1, seq + decode + 1, dtype=np.float64)
+    if window:
+        ctx = np.minimum(ctx, window)
+    return float(seq) * (min(seq, window) if window else seq) + float(ctx.sum())
+
+
+def from_arch_config(cfg, seq: int, batch: int = 1, decode: int = 0,
+                     act_bits: int = ACT_BITS, token_bits: int = TOKEN_BITS,
+                     flop_unit: float = 1.0) -> ModelProfile:
+    """Per-layer profile of an LM arch for ``batch`` requests of ``seq``
+    prompt tokens and ``decode`` generated tokens, each layer priced by its
+    kind from the model's stage specs (``models.blocks.stages_for``):
+
+    * attention: the q/k/v/o projections of every token, plus 4 FLOPs per
+      query-key pair and head dimension (scores and AV) over the layer's
+      window (:func:`_keys`);
+    * the FFN: a dense SwiGLU (or GELU) of width ``d_ff``, or the MoE's
+      router plus its ``top_k + n_shared_experts`` active experts.
+
+    Stages of another kind (recurrent, cross-attention, encoder, decoder)
+    are priced as a full-attention layer.
+
+    w[s] is the residual stream of all ``seq + decode`` positions crossing
+    the split (``act_bits`` each), w[0] the prompt's token ids, w[F] = 0;
+    the result (m_down) is the generated token ids, or the last position's
+    logits when ``decode`` is 0, and nothing at s = F. FLOPs are counted
+    in units of ``flop_unit`` (the compute constants' unit)."""
+    from repro.models.blocks import stages_for
+    d, hd = cfg.d_model, cfg.hd
+    tokens = float(seq + decode)
+    proj = 2.0 * tokens * d * (2 * cfg.n_heads + 2 * cfg.n_kv_heads) * hd
+    ffn_per_token = (6.0 if cfg.act == "swiglu" else 4.0) * d
+    fl = []
+    for spec in stages_for(cfg):
+        core = 4.0 * _keys(seq, decode, spec.window) * cfg.n_heads * hd
+        if spec.moe:
+            active = cfg.top_k + cfg.n_shared_experts
+            ffn = tokens * (ffn_per_token * cfg.moe_d_ff * active
+                            + 2.0 * d * cfg.n_experts)
+        else:
+            ffn = tokens * ffn_per_token * cfg.d_ff
+        fl += [(proj + core + ffn) * batch / flop_unit] * spec.n_layers
+    n = len(fl)
+    w = np.full(n + 1, batch * tokens * d * act_bits)
+    w[0] = batch * seq * float(token_bits)
     w[n] = 0.0
-    m_down = np.full(n + 1, float(batch * cfg.vocab_size * ACT_BITS))
+    result = (decode * token_bits if decode else cfg.vocab_size * act_bits)
+    m_down = np.full(n + 1, float(batch * result))
     m_down[n] = 0.0
     return ModelProfile(
         fl=jnp.asarray(fl, jnp.float32),
@@ -179,3 +199,13 @@ def from_arch_config(cfg, seq: int, batch: int = 1) -> ModelProfile:
         m_down=jnp.asarray(m_down, jnp.float32),
         name=getattr(cfg, "name", "lm"),
     )
+
+
+def lm(arch: dict, seq: int, decode: int = 0, batch: int = 1,
+       act_bits: int = ACT_BITS, token_bits: int = TOKEN_BITS,
+       flop_unit: float = 1.0) -> ModelProfile:
+    """:func:`from_arch_config` from JSON arguments: ``arch`` holds the
+    ``ArchConfig`` fields (a configuration file's ``model.profile_args``)."""
+    from repro.configs import ArchConfig
+    return from_arch_config(ArchConfig(**arch), seq, batch, decode,
+                            act_bits, token_bits, flop_unit)

@@ -18,10 +18,12 @@ Bit layout (LSB first; 0 = healthy):
   5 kappa          congestion estimate non-finite or past ``kappa_max``
   6 telemetry      this epoch's observation non-finite
   7 service        this epoch's modeled service times non-finite
+  8 edge           the served model's logits this epoch non-finite
 
 Bits 0-3 are planner-side (checked at replan, ``PLAN_MASK``); bits 4-6 are
 the telemetry-quarantine trigger (``TELEMETRY_MASK``); bit 7 is
-informational (service corruption surfaces in shedding/QoS).
+informational (service corruption surfaces in shedding/QoS); bit 8 is set
+by the served model's edge program (``online.edge``).
 """
 from __future__ import annotations
 
@@ -45,6 +47,7 @@ HEALTH_BITS: dict[str, int] = {
     "kappa": 5,
     "telemetry": 6,
     "service": 7,
+    "edge": 8,
 }
 
 PLAN_MASK = 0b1111
@@ -106,6 +109,12 @@ def observation_health(obs: Observation) -> Array:
 def service_health(service: Array) -> Array:
     """() int32, bit 7: modeled service times are finite."""
     return _bit(~_all_finite(service), "service")
+
+
+def edge_health(finite: Array) -> Array:
+    """() int32, bit 8: the served model's logits this epoch were finite
+    (``finite``, a device bool)."""
+    return _bit(~finite, "edge")
 
 
 def pack_health(*words: Array) -> Array:

@@ -2,7 +2,7 @@
 
 Kinds:
   attn    pre-norm GQA self-attention + MLP (optionally MoE, optionally
-          local-window)
+          local-window; the moe family tiles its window/full pattern)
   cross   cross-attention block (VLM image layers / used inside dec)
   enc     bidirectional attention + MLP, LayerNorm (whisper encoder)
   dec     causal self-attn + cross-attn + MLP, LayerNorm (whisper decoder)
@@ -167,11 +167,17 @@ def stages_for(cfg) -> list[StageSpec]:
     if fam in ("dense",):
         return [StageSpec("attn", cfg.n_layers)]
     if fam == "moe":
+        # leading dense layers, then experts; each layer's window from the
+        # window/full pattern (cfg.layer_window)
         stages = []
-        if cfg.first_dense_layers:
-            stages.append(StageSpec("attn", cfg.first_dense_layers, moe=False))
-        stages.append(StageSpec("attn", cfg.n_layers - cfg.first_dense_layers,
-                                moe=True))
+        for i in range(cfg.n_layers):
+            spec = StageSpec("attn", 1, moe=i >= cfg.first_dense_layers,
+                             window=cfg.layer_window(i))
+            if stages and dataclasses.replace(stages[-1], n_layers=1) == spec:
+                stages[-1] = dataclasses.replace(
+                    stages[-1], n_layers=stages[-1].n_layers + 1)
+            else:
+                stages.append(spec)
         return stages
     if fam == "hybrid":
         # tile block_pattern (e.g. rec,rec,attn) over depth, grouping runs

@@ -85,6 +85,11 @@ def rope(x: Array, positions: Array, theta: float) -> Array:
     return jnp.concatenate([y1, y2], axis=-1).astype(x.dtype)
 
 
+def einsum_f32(spec: str, a: Array, b: Array) -> Array:
+    """``jnp.einsum`` of compute-type operands, summed in float32."""
+    return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
+
+
 def mlp_apply(p: dict, x: Array, act: str) -> Array:
     """SwiGLU (w1/w3/w2) or GELU (w1/w2) MLP."""
     dt = COMPUTE_DTYPE

@@ -168,10 +168,13 @@ def admit(state: BatchState, now: Array, service_s: Array,
     return state
 
 
-def tick(state: BatchState) -> tuple[BatchState, Completions]:
-    """One batched edge step: every active slot advances one unit of work;
+def tick(state: BatchState, advance: bool = True
+         ) -> tuple[BatchState, Completions]:
+    """One batched edge step: every active slot advances one unit of work
+    (with ``advance``; without, the served model has set ``work`` itself);
     slots reaching zero complete and free."""
-    work = state.work - state.active.astype(jnp.int32)
+    work = (state.work - state.active.astype(jnp.int32) if advance
+            else state.work)
     done = state.active & (work <= 0)
     comp = Completions(
         valid=done,

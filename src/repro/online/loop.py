@@ -16,18 +16,29 @@ point:
     5. qos_update             -- percentiles, miss EMAs, trigger bool
     6. telemetry_update       -- measured profile EMA
 
+  device, with a served model attached (``model=``): the edge program
+  (repro.online.edge, XLA module ``jit_edge``) right after the epoch
+  program, with no host read between them -- it prefills and decodes the
+  admitted requests through slot caches, at the plan's split s* (an
+  operand), and ends the requests whose output is complete. A request's
+  edge service is then what the model did (the epochs its slot held it);
+  the device and radio parts stay the delay model's. With a model, the
+  epoch program completes what the edge ended before it admits, and
+  prices no edge compute.
+
   host (per epoch):
     - read the QoS trigger (one scalar sync, the loop's decision point)
     - OnlineSplitServer.observe(env, prof=measured, force=trigger): replan
-      on schedule or on trigger; its one sync is s* (the re-cut decision)
+      on schedule or on trigger; its one sync is the plan word (s* and the
+      plan's health)
 
 Every host read goes through repro.obs.host_read: it runs under the span
 ``sync.<name>`` (per epoch ``sync.trigger``, ``sync.plan_word``,
 ``sync.health``, ``sync.record``, ``sync.history``; at the end
 ``sync.iters``, ``sync.metrics``) and is counted per name in
 ``host_reads``, which metrics() reports. The epoch program's call runs
-under the span ``dispatch.epoch``, the server's engine call under
-``dispatch.replan``.
+under the span ``dispatch.epoch``, the edge program's under
+``dispatch.edge``, the server's engine call under ``dispatch.replan``.
 
 Because the plan enters the epoch program as a SplitPlan operand and the
 measured profile enters the planner as a ModelProfile operand (same avals
@@ -74,6 +85,7 @@ from repro.obs import host_read, recorded, span
 from repro.runtime.serve import OnlineSplitServer
 from repro.online import batcher as batcherlib
 from repro.online.batcher import BatchState, ContinuousBatcher
+from repro.online.edge import EdgeConfig, EdgeServer
 from repro.online.qos import QosConfig, QosMonitor, QosReport, QosState, qos_update
 from repro.online.streams import RequestStream, StreamConfig, StreamState, stream_step
 from repro.online.telemetry import (
@@ -122,15 +134,26 @@ class OnlineLoop:
 
     feedback=True plans against the telemetry's measured profile;
     feedback=False is the open-loop control (static profile), same epochs,
-    same traffic -- the benchmark's comparison arm."""
+    same traffic -- the benchmark's comparison arm.
+
+    ``model`` (an ArchConfig) attaches a served model at the edge, with
+    ``params`` its weights or a function drawing them from a key of the
+    episode at reset (None: online.edge.init_weights) and
+    ``served`` its edge configuration (online.edge.EdgeConfig)."""
 
     def __init__(self, scenario, engine, stream_cfg: StreamConfig,
                  service_cfg: ServiceConfig = ServiceConfig(),
                  qos_cfg: QosConfig | None = None,
                  model=None, params=None, feedback: bool = True,
                  faults: FaultConfig | None = None,
-                 degrade: LadderConfig | None = None):
+                 degrade: LadderConfig | None = None,
+                 served: EdgeConfig | None = None):
         u = scenario.cfg.n_users
+        if model is not None and degrade is not None:
+            raise ValueError("a served model runs without the degradation "
+                             "ladder")
+        self.edge = (None if model is None else EdgeServer(
+            model, served or EdgeConfig(), stream_cfg.epoch_dt_s, params))
         self.scenario = scenario
         self.engine = engine
         self.stream_cfg = stream_cfg
@@ -163,7 +186,7 @@ class OnlineLoop:
         self.qos = QosMonitor(self.qos_cfg, u)
         self.telemetry = Telemetry(engine.prof, scenario.cfg.comp,
                                    service_cfg.telemetry_decay)
-        self.server = OnlineSplitServer(engine, model, params,
+        self.server = OnlineSplitServer(engine,
                                         replan_every=service_cfg.replan_every,
                                         guard_plans=self._hardened)
         # host reads by name (repro.obs.host_read), shared with the server
@@ -171,6 +194,7 @@ class OnlineLoop:
         # episode state (device pytrees), populated by reset()
         self._sc = self._st = self._bt = self._qs = self._tel = None
         self._fs: FaultState | None = None
+        self._edge = None                    # online.edge.EdgeState
         self._plan: SplitPlan | None = None
         self._key: jax.Array | None = None
         self._fb_jit = None                  # jitted fallback plan builder
@@ -187,7 +211,9 @@ class OnlineLoop:
         """Per-user modeled service seconds + the telemetry Observation,
         both priced at the *discrete* plan (one-hot subchannels, planned
         powers/compute units) with the measured congestion inflating the
-        edge suffix. The static profile is the simulator's ground truth."""
+        edge suffix. The static profile is the simulator's ground truth.
+        With a served model the edge's part is the model's own, so the
+        service seconds leave it out."""
         prof, comp = self.engine.prof, self.scenario.cfg.comp
         s = plan.s
         pre = prof.prefix_flops()[s]
@@ -201,7 +227,8 @@ class OnlineLoop:
         speed_edge = lam(plan.r, comp) * comp.c_min_edge
         t_dev = pre / comp.c_device
         t_up = prof.w[s] / r_up
-        t_edge = suf * congestion / speed_edge
+        t_edge = (suf * congestion / speed_edge if self.edge is None
+                  else 0.0)
         t_dn = prof.m_down[s] / r_dn
         service = t_dev + t_up + t_edge + t_dn                     # (U,)
 
@@ -230,6 +257,7 @@ class OnlineLoop:
         hardened = self._hardened
         kappa_max = self._kappa_max
         shed_thr = self._shed_factor * stream_cfg.deadline_s
+        served = self.edge is not None
 
         def epoch(base_key, plan: SplitPlan, rates: injectors.FaultRates,
                   sc, st: StreamState, bt: BatchState, qs: QosState,
@@ -256,6 +284,12 @@ class OnlineLoop:
             work = jnp.clip(jnp.ceil(service / dt).astype(jnp.int32), 1,
                             svc.max_work_epochs)
             now = st.epoch.astype(jnp.float32) * dt
+            if served:
+                # The edge program ended these requests (work 0) last
+                # epoch; their slots free before this epoch's admissions,
+                # which the edge will serve until it ends them in turn.
+                bt, comps = batcherlib.tick(bt, advance=False)
+                work = jnp.ones_like(work)
             if hardened and shed_thr > 0:
                 # Admission shedding: a user whose modeled service blows
                 # past the deadline by the shed factor (deep fade, AP
@@ -273,7 +307,8 @@ class OnlineLoop:
                 bt = batcherlib.enqueue(bt, counts, now,
                                         stream_cfg.max_per_user_epoch)
                 bt = batcherlib.admit(bt, now, service, work)
-            bt, comps = batcherlib.tick(bt)
+            if not served:
+                bt, comps = batcherlib.tick(bt)
             qs, report = qos_update(qos_cfg, qs, comps)
             tel_new = telemetry_update(comp_consts, svc.telemetry_decay,
                                        self.engine.prof.fl, tel, plan.s, obs)
@@ -363,6 +398,8 @@ class OnlineLoop:
         self._tel = self.telemetry.init()
         self._fs = injectors.init_fault_state(self.scenario.cfg.n_users,
                                               self.scenario.cfg.n_aps)
+        if self.edge is not None:
+            self._edge = self.edge.init(jax.random.fold_in(key, 7))
         env0 = self.scenario.env(self._sc)
         if self._hardened:
             # Engine-plan avals without executing the solver: the fallback
@@ -388,6 +425,16 @@ class OnlineLoop:
             self._plan = self._fallback(env0)
         if self.feedback:
             self.measured_profile()        # warm the profile rebuild
+
+    @property
+    def episode_key(self) -> jax.Array:
+        """The episode's base key (set by reset)."""
+        return self._key
+
+    @property
+    def edge_state(self):
+        """The served model's slot state (online.edge.EdgeState), or None."""
+        return self._edge
 
     def measured_profile(self) -> ModelProfile:
         """The telemetry's current measured profile (a planner operand)."""
@@ -436,6 +483,9 @@ class OnlineLoop:
         device treedef stays constant across snapshot kinds."""
         if self._st is None:
             raise RuntimeError("serving_state() before reset()")
+        if self.edge is not None:
+            raise NotImplementedError("a served model's slot caches are not "
+                                      "part of the serving state")
         if self.server.state is not None:
             ps = self.server.state
             kind = "warm" if ps.warm_rho is not None else "cold"
@@ -515,6 +565,12 @@ class OnlineLoop:
              out) = self._epoch(self._key, self._plan, self._rates, self._sc,
                                 self._st, self._bt, self._qs, self._tel,
                                 self._fs)
+        if self.edge is not None:
+            with span("dispatch.edge"):
+                self._edge, self._bt, health = self.edge.step(
+                    self._edge, self._bt, self._plan.s, self._key,
+                    out.health)
+            out = out._replace(health=health)
         # the per-epoch decision sync
         trigger = bool(host_read(out.report.trigger, "trigger",
                                  self.host_reads))
@@ -617,6 +673,20 @@ class OnlineLoop:
         hist["stage"].append(self.ladder.stage if self.ladder
                              else "normal")
 
+    def _edge_counters(self) -> dict:
+        """The served model's episode counters (none without a model)."""
+        if self.edge is None:
+            return {}
+        c = self._edge.counters
+        return {"edge_prefill_tokens": c.prefill_tokens,
+                "edge_prefill_chunks": c.prefill_chunks,
+                "edge_decode_tokens": c.decode_tokens,
+                "edge_decode_steps": c.decode_steps,
+                "edge_requests_done": c.requests_done,
+                "moe_local_assignments": c.moe_local,
+                "attn_query_keys": c.attn_pairs,
+                "attn_keys_read": c.attn_keys}
+
     def metrics(self) -> dict:
         """End-of-episode summary, with ``host_reads``: the loop's and the
         server's host reads so far, by name. Syncs the episode counters
@@ -628,6 +698,7 @@ class OnlineLoop:
             "served": self._qs.served, "deadline_missed": self._qs.missed,
             "goodput": self._qs.good, "qos_triggers": self._qs.triggers,
             "epochs": self._st.epoch,
+            **self._edge_counters(),
         }, "metrics", self.host_reads)
         m.update({k: int(v) for k, v in counters.items()})
         m["duration_s"] = m["epochs"] * self.stream_cfg.epoch_dt_s

@@ -183,27 +183,24 @@ def jit_masked_decode_step(model: Model, mesh, batch: int, max_len: int):
 
 
 # --------------------------------------------------------------------------
-# online split-serve: re-plan as the scenario evolves, re-cut when s* moves
+# online split-serve: re-plan as the scenario evolves
 # --------------------------------------------------------------------------
 class OnlineSplitServer:
     """Couples a PlannerEngine to split-serve across a time-evolving scenario.
 
     Every `replan_every` epochs the engine warm-start re-plans against the
-    newly observed NetworkEnv; the (expensive) make_split_serve re-cut only
-    happens when the planned split layer actually moves. `observe(env)`
-    returns the current SplitPrograms.
+    newly observed NetworkEnv; `recuts` counts the replans that moved the
+    planned split layer. A served model takes the split as an operand of
+    its edge program (repro.online.edge), so a moved split builds nothing.
 
     The epoch loop is device-resident: the engine's replan dispatches
     asynchronously (rho gate and warm payload are traced into the compiled
     program), GD-iteration accounting accumulates in a device scalar (read
     it lazily via the `total_iters` property), and the only host sync per
-    replan is fetching the planned split layer s* -- the serve decision that
-    chooses whether to re-cut the model is inherently a host branch. That
-    read runs under the span ``sync.plan_word`` and the engine call under
-    ``dispatch.replan``; ``host_reads`` counts the server's reads by name.
-
-    model/params may be None for planning-only runs (benchmarks, tests):
-    the re-cut is then recorded but no programs are built.
+    replan is fetching the planned split layer s* (with the plan's health,
+    below). That read runs under the span ``sync.plan_word`` and the engine
+    call under ``dispatch.replan``; ``host_reads`` counts the server's
+    reads by name.
 
     The PlanState threaded across epochs carries the full warm-start payload
     (normalized optima, Adam moments + step counts, and the epoch's gains for
@@ -223,17 +220,14 @@ class OnlineSplitServer:
     checks the whole plan, not just the powers.
     """
 
-    def __init__(self, engine, model: Model | None = None, params=None,
-                 replan_every: int = 1, guard_plans: bool = True):
+    def __init__(self, engine, replan_every: int = 1,
+                 guard_plans: bool = True):
         if replan_every < 1:
             raise ValueError(f"replan_every must be >= 1, got {replan_every}")
         self.engine = engine
-        self.model = model
-        self.params = params
         self.replan_every = replan_every
         self.guard_plans = bool(guard_plans)
         self.state = None               # planning.PlanState of the last re-plan
-        self.programs: SplitPrograms | None = None
         self.split_layer: int | None = None
         self.epoch = 0
         self.recuts = 0
@@ -256,7 +250,7 @@ class OnlineSplitServer:
     def metrics(self) -> dict:
         """Counters of the server's control-plane activity: epochs seen,
         replans dispatched (and how many were QoS-forced off-schedule),
-        re-cuts of the served model, cold resets after network shape
+        replans that moved the split, cold resets after network shape
         changes, and total GD iterations (this read syncs the device
         accumulator)."""
         return {
@@ -289,10 +283,7 @@ class OnlineSplitServer:
 
     def import_host(self, state: dict, iters_acc) -> None:
         """Inverse of export_host. ``iters_acc`` is the restored device
-        scalar. When a served model is attached, the split programs are
-        re-cut at the restored split layer (the compiled split programs
-        themselves are not persisted -- they are pure functions of
-        (model, params, s))."""
+        scalar."""
         self.epoch = int(state["epoch"])
         self.recuts = int(state["recuts"])
         self.cold_resets = int(state["cold_resets"])
@@ -305,9 +296,6 @@ class OnlineSplitServer:
         self.last_plan_ok = None if ok is None else bool(ok)
         self.last_replanned = bool(state["last_replanned"])
         self._iters_acc = iters_acc
-        if self.model is not None and self.split_layer is not None:
-            self.programs = make_split_serve(self.model, self.params,
-                                             self.split_layer)
 
     def reset_warm(self) -> None:
         """Drop the warm-start payload: the next replan goes cold. The
@@ -335,10 +323,10 @@ class OnlineSplitServer:
         return split_plan_word(int(word))
 
     def observe(self, env, prof=None, force: bool = False,
-                hold: bool = False) -> SplitPrograms | None:
+                hold: bool = False) -> None:
         """Advance one epoch: re-plan on schedule (or immediately when
-        ``force`` is set -- the QoS monitor's trigger path), re-cut if s*
-        moved. ``prof`` substitutes a measured profile (repro.online
+        ``force`` is set -- the QoS monitor's trigger path), counting a
+        moved s* as a re-cut. ``prof`` substitutes a measured profile (repro.online
         telemetry) as an operand of the engine's already-compiled programs;
         None plans against the engine's static profile. ``hold`` skips the
         replan outright (the ladder's backoff posture) while still
@@ -377,8 +365,4 @@ class OnlineSplitServer:
                 if s != self.split_layer:
                     self.split_layer = s
                     self.recuts += 1
-                    if self.model is not None:
-                        self.programs = make_split_serve(self.model,
-                                                         self.params, s)
         self.epoch += 1
-        return self.programs

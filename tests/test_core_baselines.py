@@ -69,15 +69,10 @@ def test_compare_all_keys(small_env, weights, gd_cfg):
 
 
 def test_lm_profile_extraction():
-    class Cfg:
-        name = "toy"
-        n_layers = 4
-        d_model = 64
-        n_heads = 4
-        n_kv_heads = 2
-        d_ff = 128
-        vocab_size = 1000
-    p = profiles.from_arch_config(Cfg(), seq=128)
+    from repro.configs import ArchConfig
+    cfg = ArchConfig(name="toy", family="dense", n_layers=4, d_model=64,
+                     n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=1000)
+    p = profiles.from_arch_config(cfg, seq=128)
     assert p.n_layers == 4
     assert float(p.w[1]) == 128 * 64 * 16  # bf16 residual stream
     assert float(p.w[-1]) == 0.0
